@@ -3,7 +3,8 @@ the secure-aggregation ring and show the majority vote keeps training on
 the exact baseline trajectory (the paper's correctness property at tensor
 scale).
 
-Runs on 8 forced host devices (re-executes itself with XLA_FLAGS set).
+Runs on 8 forced host devices (re-executes itself with XLA_FLAGS set),
+so it is a CPU-only demo: run it with JAX_PLATFORMS=cpu.
 
     PYTHONPATH=src python examples/byzantine_training.py
 """

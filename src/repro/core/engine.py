@@ -8,7 +8,8 @@ control flow lives; the transports only move bits:
 
   * :class:`SimTransport`    — single-device oracle with the node axis
     explicit, including the batched S-session path (hops are static
-    gathers).  This is what tests pin everything else against.
+    rolls of the node axis).  This is what tests pin everything else
+    against.
   * :class:`ManualTransport` — per-rank execution inside a ``shard_map``
     that is manual over the dp axes (hops are ``lax.ppermute``, the
     intra-cluster sum is a grouped ``lax.psum``).  The training step's
@@ -56,7 +57,6 @@ from repro.kernels import backend
 from repro.kernels.secure_agg import (mask_encrypt_batch_fn,
                                       unmask_decrypt_batch_fn,
                                       vote_combine_batch_fn)
-from repro.runtime import compat
 
 _ENC_MODE = {"global": "mask", "pairwise": "pairwise", "none": "quantize"}
 
@@ -65,7 +65,7 @@ def flat_node_id(dp_axes: Sequence[str]) -> jax.Array:
     """Row-major flat rank over the dp mesh axes (inside shard_map)."""
     nid = jnp.zeros((), jnp.int32)
     for ax in dp_axes:
-        nid = nid * compat.axis_size(ax) + jax.lax.axis_index(ax)
+        nid = nid * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return nid
 
 
@@ -444,7 +444,7 @@ def tree_allreduce(tree, cfg, dp_axes: Sequence[str]):
 
 
 # ---------------------------------------------------------------------------
-# Simulation transport: node axis explicit, hops are static gathers
+# Simulation transport: node axis explicit, hops are static rolls
 # ---------------------------------------------------------------------------
 
 
@@ -476,7 +476,7 @@ class SimTransport(Transport):
         acc = q.reshape(S, g, c, T).sum(axis=2, dtype=jnp.uint32)
         return jnp.repeat(acc[:, :, None], c, axis=2).reshape(q.shape)
 
-    # wire view: (S, n, T) with the node axis explicit; hops are gathers
+    # wire view: (S, n, T) with the node axis explicit; hops are rolls
     def _wire(self, acc: jax.Array) -> jax.Array:
         return self._3d(acc)
 
@@ -491,15 +491,37 @@ class SimTransport(Transport):
         dg = digest_rows(x3.reshape(S * n, -1), self.plan.cfg.digest_words)
         return dg.reshape(S, n, -1)
 
-    def _gather(self, x3: jax.Array, src) -> jax.Array:
-        out = x3[:, np.asarray(src), :]
-        return out.reshape(out.shape[0] * out.shape[1], out.shape[2])
+    def _shift(self, x3: jax.Array, rnd: HopRound, shift: int) -> jax.Array:
+        """Node (cluster i, member m) receives the rows of node
+        (``recv_from[i]``, (m + shift) % c).  Where the cluster map is a
+        rotation by k (every ring round) this is a roll of the node axis
+        by k*c - s (s = shift % c), with a second roll for the members
+        whose m + s wraps past c: XLA:TPU compiles a gather of
+        payload-sized rows in time that grows with the payload (over
+        three minutes at n=256, T=2^20), and rolls in seconds.  Clusters
+        that do not receive this round take their own rows, which
+        :meth:`select` then discards."""
+        S, n, T = x3.shape
+        c = self.plan.cluster_size
+        g = n // c
+        src = [i if f is None else f for i, f in enumerate(rnd.recv_from)]
+        k = -src[0] % g
+        if any(f != (i - k) % g for i, f in enumerate(src)):
+            y = x3.reshape(S, g, c, T)[:, np.asarray(src)]
+            return jnp.roll(y, -shift, axis=2).reshape(S * n, T)
+        s = shift % c
+        y = jnp.roll(x3, k * c - s, axis=1)
+        if s:
+            wraps = np.arange(n) % c + s >= c
+            y = jnp.where(wraps[None, :, None],
+                          jnp.roll(x3, k * c - s + c, axis=1), y)
+        return y.reshape(S * n, T)
 
     def _move(self, rnd: HopRound, stream: int, x: jax.Array) -> jax.Array:
-        return self._gather(x, rnd.src_idx[stream])
+        return self._shift(x, rnd, stream)
 
     def _move_backup(self, rnd: HopRound, x: jax.Array) -> jax.Array:
-        return self._gather(x, rnd.backup_src)
+        return self._shift(x, rnd, 1)
 
     def select(self, rnd: HopRound, voted: jax.Array,
                acc: jax.Array) -> jax.Array:
@@ -661,7 +683,7 @@ class MeshTransport:
 
         shard = P(None, self.dp_axes, None)
         rep = P(None)
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(shard, rep, rep, {k: P(None, None)
                                         for k in mask_keys}),

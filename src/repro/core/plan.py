@@ -36,9 +36,8 @@ shape (see :func:`plan_cache_stats`).
 A plan captures:
 
   * the voted schedule as explicit :class:`HopRound`\\ s — for every
-    round, the r ``ppermute`` pair lists (mesh transports), the (r, n)
-    gather maps (simulation transport), and the per-node participation
-    mask;
+    round, its cluster-level ``recv_from`` map, the r ``ppermute`` pair
+    lists (mesh transports), and the per-node participation mask;
   * the intra-cluster ``psum`` groups;
   * the static fault model (``AggConfig.byzantine`` plus an optional
     ``SessionFaultPlan``, e.g. churn departures from an overlay epoch
@@ -339,19 +338,16 @@ class HopRound:
     """One voted schedule round, fully resolved to node granularity.
 
     ``perms[s]`` are the ``ppermute`` (src, dst) pairs of redundant copy
-    stream s; ``src_idx[s][dst]`` is the same map as a gather (what the
-    simulation transport uses); ``participates[i]`` says whether node i
-    receives this round; ``backup_perm`` is the shift-1 full-payload
-    stream the digest transport's compiled fallback rides (a rejected
-    payload is replaced by it in the same vote pass) and ``backup_src``
-    is its gather dual."""
+    stream s: receiver (cluster i, member m) takes the copy of
+    (``recv_from[i]``, (m + s) % c); ``participates[i]`` says whether
+    node i receives this round; ``backup_perm`` is the shift-1
+    full-payload stream the digest transport's compiled fallback rides
+    (a rejected payload is replaced by it in the same vote pass)."""
     combine: str                                      # add|local_plus|replace
     recv_from: tuple[Optional[int], ...]              # cluster-level round
     perms: tuple[tuple[tuple[int, int], ...], ...]    # (r, pairs)
-    src_idx: tuple[tuple[int, ...], ...]              # (r, n)
     participates: tuple[bool, ...]                    # (n,)
     backup_perm: tuple[tuple[int, int], ...]          # digest fallback hops
-    backup_src: tuple[int, ...]                       # (n,) gather dual
 
 
 def _hop_perm(n_clusters: int, cluster_size: int,
@@ -676,26 +672,14 @@ def compile_plan(cfg: AggConfig, *, epoch=None, fault=None) -> AggPlan:
     for rnd in SCH.get_schedule(cfg.schedule, g):
         perms = tuple(tuple(_hop_perm(g, c, rnd.recv_from, s))
                       for s in range(r))
-        src_idx = np.arange(n)[None, :].repeat(r, axis=0)
-        backup_src = np.arange(n)
-        participates = np.zeros((n,), bool)
-        for cl, src_cl in enumerate(rnd.recv_from):
-            if src_cl is None:
-                continue
-            for m in range(c):
-                dst = cl * c + m
-                participates[dst] = True
-                for s in range(r):
-                    src_idx[s, dst] = src_cl * c + (m + s) % c
-                backup_src[dst] = src_cl * c + (m + 1) % c
-        if not participates.any():
+        participates = tuple(src_cl is not None
+                             for src_cl in rnd.recv_from for _ in range(c))
+        if not any(participates):
             continue
         rounds.append(HopRound(
             combine=rnd.combine, recv_from=tuple(rnd.recv_from), perms=perms,
-            src_idx=tuple(tuple(int(v) for v in row) for row in src_idx),
-            participates=tuple(bool(b) for b in participates),
-            backup_perm=tuple(_hop_perm(g, c, rnd.recv_from, 1)),
-            backup_src=tuple(int(v) for v in backup_src)))
+            participates=participates,
+            backup_perm=tuple(_hop_perm(g, c, rnd.recv_from, 1))))
 
     faults = []
     if cfg.byzantine.corrupt_ranks:

@@ -13,6 +13,7 @@ import functools
 from typing import Optional, Sequence, Union
 
 import jax
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import backend
 from repro.kernels.secure_agg import ref as R
@@ -27,6 +28,31 @@ def _interp(impl: str) -> bool:
     return impl != "pallas"
 
 
+def _kernel(kernel, *args, **kwargs):
+    """Call a Pallas kernel.  XLA cannot partition a Mosaic kernel, so
+    inside a ``shard_map`` that is manual over only some mesh axes (the
+    secure train step: manual over the dp axes, automatic over "model")
+    the call is made manual over the other axes too, with its array
+    operands replicated over them."""
+    mesh = jax.sharding.get_abstract_mesh()
+    rest = frozenset(mesh.axis_names) - frozenset(mesh.manual_axes)
+    if not mesh.manual_axes or not rest:
+        return kernel(*args, **kwargs)
+    leaves, tree = jax.tree.flatten((args, kwargs))
+    dyn = [i for i, leaf in enumerate(leaves) if isinstance(leaf, jax.Array)]
+
+    def body(*arrays):
+        filled = list(leaves)
+        for i, a in zip(dyn, arrays):
+            filled[i] = a
+        a, kw = jax.tree.unflatten(tree, filled)
+        return kernel(*a, **kw)
+
+    return jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                         axis_names=rest, check_vma=False)(
+                             *(leaves[i] for i in dyn))
+
+
 def mask_encrypt_fn(x, node_id, seed, scale: float, clip: float,
                     mode: str = "mask", offset=0, cluster_size: int = 0,
                     impl: Optional[str] = None) -> jax.Array:
@@ -37,9 +63,9 @@ def mask_encrypt_fn(x, node_id, seed, scale: float, clip: float,
     if impl == "jnp":
         return R.mask_encrypt_ref(x, node_id, seed, scale, clip, mode=mode,
                                   offset=offset, cluster_size=cluster_size)
-    return mask_encrypt(x, node_id, seed, scale, clip, mode=mode,
-                        offset=offset, cluster_size=cluster_size,
-                        interpret=_interp(impl))
+    return _kernel(mask_encrypt, x, node_id, seed, scale, clip, mode=mode,
+                   offset=offset, cluster_size=cluster_size,
+                   interpret=_interp(impl))
 
 
 def unmask_decrypt_fn(agg, n_nodes: int, seed, scale: float,
@@ -50,8 +76,8 @@ def unmask_decrypt_fn(agg, n_nodes: int, seed, scale: float,
     if impl == "jnp":
         return R.unmask_decrypt_ref(agg, n_nodes, seed, scale, mode=mode,
                                     offset=offset)
-    return unmask_decrypt(agg, n_nodes, seed, scale, mode=mode,
-                          offset=offset, interpret=_interp(impl))
+    return _kernel(unmask_decrypt, agg, n_nodes, seed, scale, mode=mode,
+                   offset=offset, interpret=_interp(impl))
 
 
 def vote_combine_fn(copies: Union[jax.Array, Sequence[jax.Array]], acc,
@@ -61,7 +87,7 @@ def vote_combine_fn(copies: Union[jax.Array, Sequence[jax.Array]], acc,
     impl = backend.resolve(impl)
     if impl == "jnp":
         return R.vote_combine_ref(copies, acc)
-    return vote_combine(copies, acc, interpret=_interp(impl))
+    return _kernel(vote_combine, copies, acc, interpret=_interp(impl))
 
 
 # ---------------------------------------------------------------------------
@@ -82,9 +108,9 @@ def mask_encrypt_batch_fn(x, node_ids, seeds, scale: float, clip: float,
         return R.mask_encrypt_batch_ref(x, node_ids, seeds, scale, clip,
                                         mode=mode, offsets=offsets,
                                         cluster_size=cluster_size)
-    return mask_encrypt_batch(x, node_ids, seeds, scale, clip, mode=mode,
-                              offsets=offsets, cluster_size=cluster_size,
-                              interpret=_interp(impl))
+    return _kernel(mask_encrypt_batch, x, node_ids, seeds, scale, clip,
+                   mode=mode, offsets=offsets, cluster_size=cluster_size,
+                   interpret=_interp(impl))
 
 
 def unmask_decrypt_batch_fn(agg, n_nodes: int, seeds, scale: float,
@@ -95,8 +121,8 @@ def unmask_decrypt_batch_fn(agg, n_nodes: int, seeds, scale: float,
     if impl == "jnp":
         return R.unmask_decrypt_batch_ref(agg, n_nodes, seeds, scale,
                                           mode=mode, offsets=offsets)
-    return unmask_decrypt_batch(agg, n_nodes, seeds, scale, mode=mode,
-                                offsets=offsets, interpret=_interp(impl))
+    return _kernel(unmask_decrypt_batch, agg, n_nodes, seeds, scale,
+                   mode=mode, offsets=offsets, interpret=_interp(impl))
 
 
 def vote_combine_batch_fn(copies: Sequence[jax.Array], acc,

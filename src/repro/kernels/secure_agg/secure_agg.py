@@ -368,14 +368,20 @@ def as_copy_list(copies: Union[jax.Array, Sequence[jax.Array]]
 
 
 def median_network(rows: list[jax.Array]) -> jax.Array:
-    """Odd-even transposition sort over a tiny list; returns the median."""
+    """Odd-even transposition sort over a tiny list; returns the median.
+
+    Each compare-exchange is one comparison and two selects, not
+    ``jnp.minimum``/``jnp.maximum``: Mosaic has no lowering for unsigned
+    vector min/max (``arith.minui``), while an unsigned compare and a
+    select lower natively and give the same result."""
     rows = list(rows)
     r = len(rows)
     for phase in range(r):
         for i in range(phase % 2, r - 1, 2):
-            lo = jnp.minimum(rows[i], rows[i + 1])
-            hi = jnp.maximum(rows[i], rows[i + 1])
-            rows[i], rows[i + 1] = lo, hi
+            a, b = rows[i], rows[i + 1]
+            swap = b < a
+            rows[i], rows[i + 1] = (jnp.where(swap, b, a),
+                                    jnp.where(swap, a, b))
     return rows[r // 2]
 
 

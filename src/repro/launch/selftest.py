@@ -1,8 +1,9 @@
 """Multi-device self-test for the distributed secure aggregation path.
 
-Runs with forced host devices (set BEFORE jax import):
+Runs with forced host devices (set BEFORE jax import), so it is a
+CPU-only tool; on a TPU host, ``chip_smoke.py`` is the check:
 
-    REPRO_SELFTEST_DEVICES=16 python -m repro.launch.selftest
+    JAX_PLATFORMS=cpu REPRO_SELFTEST_DEVICES=16 python -m repro.launch.selftest
 
 Verifies, for every (schedule x transport x masking) combination:
   * distributed MeshTransport result == single-device SimTransport oracle

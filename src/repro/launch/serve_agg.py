@@ -39,7 +39,7 @@ the shared metrics registry; ``--stats-interval N`` prints the human
 metrics table every N sessions while the load runs.
 
 Mesh/compat bootstrap is shared with ``launch.serve`` via
-``runtime.compat.host_mesh`` (one place for jax-version shims);
+``runtime.compat.host_mesh`` (one place for the mesh bootstrap);
 ``REPRO_KERNEL_IMPL`` (or ``--impl``) picks the kernel engine exactly as
 in the single-query path.
 """
@@ -52,6 +52,7 @@ import time
 import numpy as np
 
 from repro.api import Runtime, SecureAggregator, Security, Topology
+from repro.core.masking import quantization_error_bound
 from repro.core.overlay import build_overlay
 from repro.launch.mesh import make_host_mesh
 from repro.obs import DEFAULT_REGISTRY, TraceRecorder, stats_table
@@ -130,18 +131,26 @@ def run_func_load(agg: SecureAggregator, em: EpochManager, *,
 def run_load(agg: SecureAggregator, em: EpochManager, *, sessions: int,
              elems: int, churn_every: int, seed: int = 0,
              stats_interval: int = 0) -> dict:
+    """Drive ``sessions`` additive sessions of ``elems`` float32 updates
+    per slot, drawn uniformly from the clip range.  A revealed sum is
+    exact when it is within the quantization bound of the float64 sum
+    (plus the float32 rounding of the revealed value)."""
     rng = np.random.default_rng(seed)
-    n = agg.cfg.n_nodes
+    n, clip = agg.cfg.n_nodes, agg.cfg.clip
+    tol = (quantization_error_bound(agg.cfg.mask_cfg())
+           + n * clip * float(np.finfo(np.float32).eps))
     expected: dict[int, np.ndarray] = {}
     t0 = time.monotonic()
     for i in range(sessions):
         if churn_every and i and i % churn_every == 0:
             em.churn(joins=4, leaves=4, honest_join_frac=1.0)
         s = agg.open_session(elems, now=time.monotonic())
-        vals = rng.integers(0, 2, size=(n, elems)).astype(np.float32)
+        vals = rng.random((n, elems), dtype=np.float32)
+        vals *= np.float32(2 * clip)
+        vals -= np.float32(clip)
         for slot in range(n):
             s.contribute(slot, vals[slot])
-        expected[s.sid] = vals.sum(0)
+        expected[s.sid] = vals.sum(0, dtype=np.float64)
         agg.seal(s.sid, now=time.monotonic())
         agg.pump()                       # watermark-driven flushes
         if stats_interval and (i + 1) % stats_interval == 0:
@@ -153,7 +162,7 @@ def run_load(agg: SecureAggregator, em: EpochManager, *, sessions: int,
     revealed = [sid for sid in expected
                 if svc.get(sid).state is SessionState.REVEALED]
     exact = sum(
-        bool(np.allclose(agg.result(sid), expected[sid], atol=1e-3))
+        bool(np.abs(agg.result(sid) - expected[sid]).max() <= tol)
         for sid in revealed)
     return {"wall_s": wall, "sessions": sessions,
             "sessions_per_s": sessions / max(wall, 1e-9),
@@ -324,6 +333,10 @@ def main() -> None:
         with open(args.metrics_out, "w") as f:
             f.write(prometheus_text(agg.metrics))
         print(f"metrics: snapshot -> {args.metrics_out}")
+    if out["revealed"] < out["sessions"] or out["exact"] < out["revealed"]:
+        raise SystemExit(
+            f"serve_agg: revealed {out['revealed']}/{out['sessions']}, "
+            f"exact {out['exact']}/{out['revealed']}")
 
 
 if __name__ == "__main__":

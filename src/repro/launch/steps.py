@@ -22,7 +22,6 @@ from repro.launch import sharding as SH
 from repro.launch.mesh import dp_axes_of
 from repro.models import model as M
 from repro.optim import adamw
-from repro.runtime import compat
 from repro.runtime.context import DistCtx, use_ctx
 
 # ---------------------------------------------------------------------------
@@ -220,7 +219,7 @@ def build_secure_train_step(cfg: ModelConfig, mesh: jax.sharding.Mesh,
     out_specs = (_project_specs(pspecs, dp_axes),
                  _project_specs(ospecs, dp_axes),
                  {"loss": P(), "grad_norm": P(), "lr": P()})
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         dp_body, mesh=mesh,
         in_specs=in_specs, out_specs=out_specs,
         check_vma=False,

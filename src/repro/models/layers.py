@@ -18,7 +18,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import (ATTN, ATTN_CHUNKED, CROSS_ATTN, DENSE, MAMBA2,
                                 MOE, NONE, LayerSpec, ModelConfig)
-from repro.runtime import compat
 from repro.runtime.context import constrain, get_ctx
 
 # ---------------------------------------------------------------------------
@@ -540,7 +539,7 @@ def moe_distributed_replicated(cfg: ModelConfig, p: dict, x: jax.Array,
     m = cfg.moe
     B, S, D = x.shape
     T = B * S
-    n_ep = compat.axis_size(ep_axis)
+    n_ep = jax.lax.axis_size(ep_axis)
     E_loc = p["w_gate"].shape[0]
     E = E_loc * n_ep
     xf = x.reshape(T, D)
@@ -575,7 +574,7 @@ def moe_distributed(cfg: ModelConfig, p: dict, x: jax.Array,
     m = cfg.moe
     B, S, D = x.shape
     T = B * S
-    n_ep = compat.axis_size(ep_axis)
+    n_ep = jax.lax.axis_size(ep_axis)
     E_loc = p["w_gate"].shape[0]
     E = E_loc * n_ep
     xf = x.reshape(T, D)
@@ -657,7 +656,7 @@ def _moe_forward_impl(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
         lambda l: P(ep, *([None] * (l.ndim - 1))) if l.ndim == 3
         else P(*([None] * l.ndim)), p)
     x_spec = P(None, None, None) if replicated_tokens else P(ep, None, None)
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(p_specs, x_spec), out_specs=x_spec,
         axis_names=frozenset({ep}), check_vma=False)(p, x)

@@ -1,25 +1,22 @@
-"""Version compatibility shims for jax APIs that moved between releases."""
+"""Mesh builders every driver, benchmark and test shares."""
 from __future__ import annotations
 
 import jax
+import numpy as np
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with explicit-Auto axis types where the API has
-    them (0.5+); older releases are Auto-only and take no kwarg."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto``: the model's sharding
+    rules are written for GSPMD propagation, not explicit-axis typing."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def host_mesh(data: int = 1, model: int = 1,
               pod: int = 0) -> jax.sharding.Mesh:
     """The one mesh bootstrap every CLI driver shares (``launch.serve``,
     ``launch.serve_agg``, tests): a small mesh over the host's devices,
-    built through :func:`make_mesh` so the jax-version shims apply in one
-    place instead of being duplicated per driver."""
+    built through :func:`make_mesh`."""
     if pod:
         shape, axes = (pod, data, model), ("pod", "data", "model")
     else:
@@ -32,38 +29,7 @@ def node_mesh(n_nodes: int, axis: str = "data") -> jax.sharding.Mesh:
     devices — the shared bootstrap for ``MeshTransport`` drivers and
     benches (keeps device ordering / axis naming in one place, like
     :func:`host_mesh` does for the LM drivers)."""
-    import numpy as np
     devs = jax.devices()
     assert len(devs) >= n_nodes, \
         f"mesh transport needs {n_nodes} devices (have {len(devs)})"
     return jax.sharding.Mesh(np.array(devs[:n_nodes]), (axis,))
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` with a pre-0.5 fallback (a psum of the static
-    constant 1 folds to the axis size at trace time)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
-              axis_names=None):
-    """``jax.shard_map`` (new API) with fallback to
-    ``jax.experimental.shard_map.shard_map`` (pre-0.6 releases, where the
-    replication check kwarg is spelled ``check_rep`` and partial-manual
-    mode is requested via ``auto=`` — the complement of ``axis_names``)."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)

@@ -31,18 +31,42 @@ def test_plan_round_layout(schedule, n_rounds):
     assert plan.groups == ((0, 1, 2, 3), (4, 5, 6, 7), (8, 9, 10, 11),
                            (12, 13, 14, 15))
     for rnd in plan.rounds:
-        assert len(rnd.perms) == 3 and len(rnd.src_idx) == 3
-        # ppermute pairs and gather maps describe the same hop
+        assert len(rnd.perms) == 3
+        src_of = [{dst: src for src, dst in rnd.perms[s]} for s in range(3)]
+        # every participating node receives exactly one copy per stream
         for s in range(3):
-            for src, dst in rnd.perms[s]:
-                assert rnd.src_idx[s][dst] == src
-                assert rnd.participates[dst]
+            assert sorted(src_of[s]) == [d for d in range(16)
+                                         if rnd.participates[d]]
         # shift-s copies come from distinct members of the same cluster
-        for dst in range(16):
-            if rnd.participates[dst]:
-                srcs = {rnd.src_idx[s][dst] for s in range(3)}
-                assert len(srcs) == 3
-                assert len({src // 4 for src in srcs}) == 1
+        for dst in src_of[0]:
+            srcs = {src_of[s][dst] for s in range(3)}
+            assert len(srcs) == 3
+            assert {src // 4 for src in srcs} == {rnd.recv_from[dst // 4]}
+
+
+@pytest.mark.parametrize("schedule,n,c", [("ring", 16, 4), ("ring", 12, 3),
+                                          ("ring", 8, 1), ("tree", 16, 4),
+                                          ("butterfly", 16, 2)])
+def test_sim_hop_moves_rows_along_ppermute_pairs(schedule, n, c):
+    """The sim transport's rolled hop delivers, on every copy stream and
+    the backup stream, exactly the rows the mesh transport's ppermute
+    pairs deliver."""
+    import jax.numpy as jnp
+    from repro.core.engine import SimTransport
+    r = 3 if c >= 3 else 1
+    plan = compile_plan(AggConfig(n_nodes=n, cluster_size=c, redundancy=r,
+                                  schedule=schedule))
+    tp = SimTransport(plan, S=2, impl="jnp")
+    x = np.arange(2 * n * 5, dtype=np.uint32).reshape(2, n, 5)
+    for rnd in plan.rounds:
+        moves = [(tp._move(rnd, s, jnp.asarray(x)), rnd.perms[s])
+                 for s in range(r)]
+        moves.append((tp._move_backup(rnd, jnp.asarray(x)),
+                      rnd.backup_perm))
+        for got, pairs in moves:
+            got = np.asarray(got).reshape(2, n, 5)
+            for src, dst in pairs:
+                assert np.array_equal(got[:, dst], x[:, src]), (src, dst)
 
 
 def test_plan_folds_static_faults_and_epoch_layout():

@@ -72,6 +72,38 @@ def test_vote_combine_kernel_matches_jnp(T, r):
                         == majority_vote(stacked)))
 
 
+@pytest.mark.parametrize("r", [3, 5])
+def test_vote_median_is_unsigned_across_sign_bit(r):
+    """The compare-and-select median network keeps the unsigned order
+    of the min/max network it replaced: copies that straddle 2^31 (where
+    a signed compare would flip the order) vote to the numpy unsigned
+    median on the jnp engine and in the Pallas kernel alike."""
+    edge = np.array([0, 1, 0x7FFFFFFE, 0x7FFFFFFF, 0x80000000, 0x80000001,
+                     0xFFFFFFFE, 0xFFFFFFFF], np.uint32)
+    T = 4096
+    rng = np.random.default_rng(r)
+    host = np.where(rng.random((r, T)) < 0.75,
+                    rng.choice(edge, size=(r, T)),
+                    rng.integers(0, 2 ** 32, size=(r, T), dtype=np.uint32))
+    copies = [jnp.asarray(row) for row in host]
+    acc = jnp.asarray(rng.integers(0, 2 ** 32, size=(T,), dtype=np.uint32))
+
+    def minmax_median(rows):       # the network as it was written before
+        rows = list(rows)
+        for phase in range(r):
+            for i in range(phase % 2, r - 1, 2):
+                rows[i], rows[i + 1] = (jnp.minimum(rows[i], rows[i + 1]),
+                                        jnp.maximum(rows[i], rows[i + 1]))
+        return rows[r // 2]
+
+    want = np.sort(host, axis=0)[r // 2]
+    assert np.array_equal(np.asarray(minmax_median(copies)), want)
+    assert np.array_equal(np.asarray(majority_vote_list(copies)), want)
+    for impl in ("jnp", "pallas_interpret"):
+        got = np.asarray(vote_combine_op(tuple(copies), acc, impl=impl))
+        assert np.array_equal(got, np.asarray(acc) + want), impl
+
+
 # --- pairwise masking fused in-kernel (fori_loop over cluster members) ----
 
 
@@ -280,7 +312,6 @@ import json, numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.engine import manual_allreduce
 from repro.core.plan import AggConfig
-from repro.runtime import compat
 
 def count_eqns(jaxpr, counts):
     for eqn in jaxpr.eqns:
@@ -303,7 +334,7 @@ def trace(n_nodes, cluster_size):
     cfg = AggConfig(n_nodes=n_nodes, cluster_size=cluster_size,
                     redundancy=3, schedule="tree")
     mesh = Mesh(np.array(jax.devices()[:n_nodes]), ("data",))
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda x: manual_allreduce(x[0], cfg, ("data",))[None],
         mesh=mesh, in_specs=(P("data"),), out_specs=P("data"),
         check_vma=False)
