@@ -7,7 +7,7 @@ PY := PYTHONPATH=src python
 
 .PHONY: test api-lane kernel-lane service-lane mesh-lane adversary-lane \
     chaos-lane obs-lane tune-lane funcs-lane bench-service \
-    bench-service-mesh bench-stream bench-obs bench-tune bench-funcs \
+    bench-service-mesh bench-stream bench-tune bench-funcs \
     bench
 
 test:
@@ -106,11 +106,6 @@ bench-stream:
 	    $(PY) -m benchmarks.run --only service --transport mesh \
 	    --json BENCH_service.json \
 	    --guard service_throughput_mesh_S64_sps
-
-# instrumentation overhead gate: metrics_on must stay within 2% of a
-# disabled registry on the batched dispatch path
-bench-obs:
-	$(PY) -m benchmarks.run --only obs_overhead --json BENCH_service.json
 
 # tuner decision trajectory + resolution-overhead gate: the headline
 # decision's predicted bytes may not regress (grow) >10% vs the value

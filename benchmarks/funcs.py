@@ -13,7 +13,7 @@ Two claims to pin:
     protocol change that silently inflates the bisection's per-round
     bytes >10% fails the gate.
 
-Timing rows (min over interleaved rounds, obs_overhead methodology):
+Timing rows (min over interleaved rounds):
 the one-shot verb wall time, histogram vs an 8-round median — the
 median's sequential reveal-between-rounds dispatches are the price of
 non-additivity the README table documents.

@@ -89,8 +89,7 @@ def main() -> None:
                  "to diff against)")
 
     from benchmarks import (comm_cost, crypto_breakdown, funcs, kernels,
-                            lower_bound, obs_overhead, secure_allreduce,
-                            service, tune)
+                            lower_bound, secure_allreduce, service, tune)
     table = {
         "comm_cost": comm_cost.run,                # paper Fig 3a/3b
         "crypto_breakdown": crypto_breakdown.run,  # paper Fig 3c/3d
@@ -99,7 +98,6 @@ def main() -> None:
         "kernels": kernels.run,                    # pallas kernel microbench
         "service": functools.partial(              # multi-session load gen
             service.run, transport=args.transport),
-        "obs_overhead": obs_overhead.run,          # metrics/trace cost gate
         "tune": tune.run,                          # tuner decisions + gate
         "funcs": funcs.run,                        # secure-function layer
     }

@@ -14,9 +14,8 @@ Two claims to pin (``repro.tune``, PR 9):
     resolves every repeat dispatch through one memo lookup, required to
     stay within 2% of a facade constructed directly with the winning
     config (same plan, same compiled executable — the only delta IS the
-    resolution).  Methodology follows ``benchmarks/obs_overhead``:
-    interleaved one-dispatch rounds, min over rounds, S=64 batched
-    lane.  The gate is ENFORCED: a breach raises, which
+    resolution).  Methodology: interleaved one-dispatch rounds, min
+    over rounds, S=64 batched lane.  The gate is ENFORCED: a breach raises, which
     ``benchmarks/run.py`` turns into an ERROR row and a non-zero exit.
 """
 from __future__ import annotations

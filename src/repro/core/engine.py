@@ -60,6 +60,11 @@ from repro.kernels.secure_agg import (mask_encrypt_batch_fn,
 
 _ENC_MODE = {"global": "mask", "pairwise": "pairwise", "none": "quantize"}
 
+# The ``jax.named_scope`` of each protocol stage in ``execute_chunks``;
+# voted round i wraps its hop, vote and select in ``agg.round_<i>``.
+STAGE_SCOPES = ("agg.encrypt", "agg.cluster_sum", "agg.hop", "agg.vote",
+                "agg.select", "agg.reveal_rows", "agg.unmask")
+
 
 def flat_node_id(dp_axes: Sequence[str]) -> jax.Array:
     """Row-major flat rank over the dp mesh axes (inside shard_map)."""
@@ -258,50 +263,68 @@ def execute_chunks(plan: AggPlan, tp: Transport, chunks: list,
     ``[k*Tc, (k+1)*Tc)`` past each session's counter offset, so chunked
     and monolithic payloads produce identical streams.  Per round, chunk
     k+1's hop is issued before chunk k's vote (double-buffered software
-    pipeline — communication overlaps vote compute)."""
+    pipeline — communication overlaps vote compute).
+
+    Every stage runs under a ``jax.named_scope`` (see ``STAGE_SCOPES``),
+    so each op of every transport's program carries its protocol stage
+    in its ``op_name`` metadata, where a profiler trace can read it; the
+    scopes change metadata only, never the compiled program."""
     mcfg = plan.mask_cfg()
     c = plan.cluster_size
-    node_ids = tp.node_ids()
-    row_seeds = tp.expand(meta.seeds)
-    row_offs = tp.expand(meta.offsets)
     K = len(chunks)
     Tc = chunks[0].shape[-1]
 
-    def off(k):
-        delta = plan.chunk_offset(k, Tc)
-        return row_offs if not delta else row_offs + jnp.uint32(delta)
-
     # --- Step 1: encrypt (fused clip+quantize+pad, incl. pairwise) ---
-    qs = [mask_encrypt_batch_fn(ch, node_ids, row_seeds, mcfg.scale,
-                                mcfg.clip, mode=_ENC_MODE[mcfg.mode],
-                                offsets=off(k), cluster_size=c, impl=tp.impl)
-          for k, ch in enumerate(chunks)]
+    with jax.named_scope("agg.encrypt"):
+        node_ids = tp.node_ids()
+        row_seeds = tp.expand(meta.seeds)
+        row_offs = tp.expand(meta.offsets)
+
+        def off(k):
+            delta = plan.chunk_offset(k, Tc)
+            return row_offs if not delta else row_offs + jnp.uint32(delta)
+
+        qs = [mask_encrypt_batch_fn(ch, node_ids, row_seeds, mcfg.scale,
+                                    mcfg.clip, mode=_ENC_MODE[mcfg.mode],
+                                    offsets=off(k), cluster_size=c,
+                                    impl=tp.impl)
+              for k, ch in enumerate(chunks)]
 
     # --- Steps 1-2: intra-cluster modular sum (pairwise pads cancel) ---
-    accs = [tp.cluster_sum(q) for q in qs]
+    with jax.named_scope("agg.cluster_sum"):
+        accs = [tp.cluster_sum(q) for q in qs]
 
     # --- Step 3: voted schedule; hops pipelined over chunks ---
     locals_ = list(accs)
     for ri, rnd in enumerate(plan.rounds):
-        inflight = tp.hop(rnd, ri, meta, accs[0])
-        new_accs = []
-        for k in range(K):
-            nxt = tp.hop(rnd, ri, meta, accs[k + 1]) if k + 1 < K else None
-            voted = tp.vote(rnd, inflight, _vote_base(rnd, accs[k],
-                                                      locals_[k]))
-            new_accs.append(tp.select(rnd, voted, accs[k]))
-            inflight = nxt
-        accs = new_accs
+        with jax.named_scope(f"agg.round_{ri}"):
+            with jax.named_scope("agg.hop"):
+                inflight = tp.hop(rnd, ri, meta, accs[0])
+            new_accs = []
+            for k in range(K):
+                with jax.named_scope("agg.hop"):
+                    nxt = (tp.hop(rnd, ri, meta, accs[k + 1])
+                           if k + 1 < K else None)
+                with jax.named_scope("agg.vote"):
+                    voted = tp.vote(rnd, inflight,
+                                    _vote_base(rnd, accs[k], locals_[k]))
+                with jax.named_scope("agg.select"):
+                    new_accs.append(tp.select(rnd, voted, accs[k]))
+                inflight = nxt
+            accs = new_accs
 
     # --- Step 4: threshold decryption (fused unmask+dequantize) ---
     if reveal_only:
         # ``off`` closes over row_offs, so it now yields per-revealed-row
         # offsets automatically
-        accs, row_seeds, row_offs = tp.reveal_rows(accs, meta)
+        with jax.named_scope("agg.reveal_rows"):
+            accs, row_seeds, row_offs = tp.reveal_rows(accs, meta)
     umode = "mask" if mcfg.mode == "global" else "dequantize"
-    return [unmask_decrypt_batch_fn(a, mcfg.n_nodes, row_seeds, mcfg.scale,
-                                    mode=umode, offsets=off(k), impl=tp.impl)
-            for k, a in enumerate(accs)]
+    with jax.named_scope("agg.unmask"):
+        return [unmask_decrypt_batch_fn(a, mcfg.n_nodes, row_seeds,
+                                        mcfg.scale, mode=umode,
+                                        offsets=off(k), impl=tp.impl)
+                for k, a in enumerate(accs)]
 
 
 # ---------------------------------------------------------------------------

@@ -178,6 +178,7 @@ def mask_encrypt(x: jax.Array, node_id, seed, scale: float, clip: float,
         ],
         out_specs=pl.BlockSpec((tr, LANES), lambda ib: (ib, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_p, LANES), jnp.uint32),
+        name="mask_encrypt",
         interpret=backend.interpret_default(interpret),
     )(x2, meta)
     return out.reshape(-1)[:T]
@@ -228,6 +229,7 @@ def unmask_decrypt(agg: jax.Array, n_nodes: int, seed, scale: float,
         ],
         out_specs=pl.BlockSpec((tr, LANES), lambda ib: (ib, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_p, LANES), jnp.float32),
+        name="unmask_decrypt",
         interpret=backend.interpret_default(interpret),
     )(a2, meta)
     return out.reshape(-1)[:T]
@@ -298,6 +300,7 @@ def mask_encrypt_batch(x: jax.Array, node_ids, seeds, scale: float,
         ],
         out_specs=pl.BlockSpec((1, tr, LANES), lambda ib, it: (ib, it, 0)),
         out_shape=jax.ShapeDtypeStruct((B, rows_p, LANES), jnp.uint32),
+        name="mask_encrypt_batch",
         interpret=backend.interpret_default(interpret),
     )(x3, meta)
     return out.reshape(B, -1)[:, :T]
@@ -347,6 +350,7 @@ def unmask_decrypt_batch(agg: jax.Array, n_nodes: int, seeds, scale: float,
         ],
         out_specs=pl.BlockSpec((1, tr, LANES), lambda ib, it: (ib, it, 0)),
         out_shape=jax.ShapeDtypeStruct((B, rows_p, LANES), jnp.float32),
+        name="unmask_decrypt_batch",
         interpret=backend.interpret_default(interpret),
     )(a3, meta)
     return out.reshape(B, -1)[:, :T]
@@ -413,6 +417,7 @@ def vote_combine(copies: Union[jax.Array, Sequence[jax.Array]],
         in_specs=[spec] * (r + 1),
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows_p, LANES), jnp.uint32),
+        name="vote_combine",
         interpret=backend.interpret_default(interpret),
     )(*[_to_tiles(c, rows_p) for c in copies], _to_tiles(acc, rows_p))
     return out.reshape(-1)[:T]

@@ -16,8 +16,7 @@ Design constraints, in order:
   * **off-hot-path** — a metric handle is allocated once
     (``registry.counter(name, **labels)``) and updated with a plain
     attribute add (``c.inc()``); no dict lookup, no string formatting,
-    no clock read on the update path.  ``benchmarks/obs_overhead.py``
-    pins the cost;
+    no clock read on the update path;
   * **deterministic** — the registry clock is injectable
     (``clock=...``), and nothing here ever calls ``time`` unless asked
     to, so byte-identical replay of a traced run stays byte-identical;
@@ -25,9 +24,7 @@ Design constraints, in order:
 
 Series are keyed by (name, sorted label items); ``snapshot()`` returns
 plain nested dicts (the ``svc.stats["metrics"]`` payload), ``reset()``
-zeroes every series in place (handles stay valid).  A registry built
-with ``enabled=False`` hands out no-op handles — the baseline the
-overhead bench compares against.
+zeroes every series in place (handles stay valid).
 
 Metric-name and stats-schema constants live here (not in the service)
 so the docs, the exporters, and the tests pin one vocabulary.
@@ -78,6 +75,9 @@ M_TUNER_PROBES = "tuner.probes"              # measured micro-dispatches
 # ``pack_overlap`` is the host-side pack + non-blocking dispatch issue
 # (overlapped with the previous batch's device work — JAX async
 # dispatch) and ``device_dispatch`` becomes the blocking wait at reveal.
+# The streaming wait and every batch's reveal are observed by the
+# ``svc.settle`` / ``svc.reveal`` trace spans (``obs.spans.span``) over
+# the same interval, so the profiler's trace shows them too.
 H_STAGE = "stage.seconds"
 STAGES = ("admission_wait", "plan_compile", "device_dispatch", "reveal",
           "pack_overlap")
@@ -173,37 +173,6 @@ class Histogram:
         return out
 
 
-class _Noop:
-    """Handle handed out by a disabled registry: every update is a
-    no-op, every read is zero (the overhead-bench baseline)."""
-
-    __slots__ = ()
-    value = 0
-    count = 0
-    total = 0.0
-
-    def inc(self, n: int = 1) -> None:
-        pass
-
-    def set(self, v: float) -> None:
-        pass
-
-    def track_max(self, v: float) -> None:
-        pass
-
-    def observe(self, v: float) -> None:
-        pass
-
-    def reset(self) -> None:
-        pass
-
-    def snapshot(self):
-        return 0
-
-
-_NOOP = _Noop()
-
-
 def _series_key(name: str, labels: dict) -> tuple:
     return (name, tuple(sorted(labels.items())))
 
@@ -225,17 +194,13 @@ class MetricsRegistry:
     directly.  ``clock`` is carried for exporters that want timestamps;
     nothing on the update path reads it."""
 
-    def __init__(self, clock: Callable[[], float] = time.monotonic,
-                 enabled: bool = True):
+    def __init__(self, clock: Callable[[], float] = time.monotonic):
         self.clock = clock
-        self.enabled = enabled
         self._counters: dict[tuple, Counter] = {}
         self._gauges: dict[tuple, Gauge] = {}
         self._histograms: dict[tuple, Histogram] = {}
 
     def _get(self, store: dict, cls, name: str, labels: dict):
-        if not self.enabled:
-            return _NOOP
         key = _series_key(name, labels)
         s = store.get(key)
         if s is None:

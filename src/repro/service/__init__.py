@@ -25,6 +25,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from repro.core.plan import plan_cache_stats
+from repro.obs.spans import span
 from repro.runtime.resilience import CircuitBreaker, RetryPolicy
 from repro.service.epochs import EpochManager, EpochSnapshot
 from repro.service.executor import (AdmissionQueue, BatchedExecutor,
@@ -137,8 +138,9 @@ class AggregationService:
     def seal(self, sid: int, now: Optional[float] = None) -> None:
         now = time.monotonic() if now is None else now
         s = self._sessions[sid]
-        s.seal(now)
-        self.queue.submit(s, now=now)
+        with span("svc.seal", sid=sid):
+            s.seal(now)
+            self.queue.submit(s, now=now)
 
     def pump(self, now: Optional[float] = None, force: bool = False) -> int:
         """Flush ready batches; returns number of sessions revealed."""

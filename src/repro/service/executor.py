@@ -97,6 +97,7 @@ from repro.core.engine import (MeshTransport, SimTransport,
 from repro.core.plan import (SessionMeta, compile_plan, fault_masks_of,
                              _require)
 from repro.obs import metrics as M
+from repro.obs.spans import span
 from repro.obs.trace import TraceRecorder, record_batch_trace
 from repro.runtime.chaos import (ChaosConfig, ChaosError, ChaosSchedule,
                                  ChaosTransport)
@@ -443,12 +444,13 @@ class BatchedExecutor:
         f32, u32 = jnp.float32, jnp.uint32
 
         def build():
-            return fn.lower(
-                jax.ShapeDtypeStruct((S, n, padded), f32),
-                jax.ShapeDtypeStruct((S,), u32),
-                jax.ShapeDtypeStruct((S,), u32),
-                {m: jax.ShapeDtypeStruct((S, n), jnp.bool_)
-                 for m in modes}).compile()
+            with span("svc.compile"):
+                return fn.lower(
+                    jax.ShapeDtypeStruct((S, n, padded), f32),
+                    jax.ShapeDtypeStruct((S,), u32),
+                    jax.ShapeDtypeStruct((S,), u32),
+                    {m: jax.ShapeDtypeStruct((S, n), jnp.bool_)
+                     for m in modes}).compile()
 
         self._warming[key] = self._pool.submit(build)
 
@@ -492,7 +494,7 @@ class BatchedExecutor:
 
     # -- one dispatch attempt ----------------------------------------------
     def _dispatch(self, sessions: Sequence[Session], padded: int,
-                  backend: str, fault: Optional[ChaosConfig]):
+                  backend: str, fault: Optional[ChaosConfig], unit: int):
         """Pack + issue one batch WITHOUT the host sync: returns
         ``(revealed, owner, fresh, rows, masks)`` where ``revealed`` is
         the (possibly still in-flight) device result of the first
@@ -500,7 +502,9 @@ class BatchedExecutor:
         the caller slices ``[:rows]`` after its ``np.asarray`` sync) and
         ``masks`` are the real rows' fault masks (what the trace
         records).  Session state is untouched, so a failed attempt
-        stays retriable."""
+        stays retriable.  The pack, the host-to-device copy and the call
+        are the ``svc.pack`` / ``svc.put`` / ``svc.issue`` (or, on a
+        cache miss, ``svc.compile``) spans of retry unit ``unit``."""
         if fault is not None and fault.mode == "dispatch":
             raise ChaosError(
                 f"chaos: injected dispatch failure "
@@ -534,39 +538,43 @@ class BatchedExecutor:
         # — fill_payload_rows writes every byte of the real rows, so no
         # pre-zeroing; the buffer returns to the pool once this batch
         # settles (its executable is done reading the staged copy)
-        xs = self._buf_take((S_exec, n_nodes, padded))
-        r = 0
-        for s in sessions:
-            r += s.fill_payload_rows(xs, r, padded)
-        dm = masks
-        if S_exec > R:
-            # shape-bucket dispatch: dummy zero rows (zero payload, zero
-            # seed/offset, no faults) — batch rows are independent
-            # sessions, so the real rows' outputs are bit-identical and
-            # the dummies are sliced off after the sync
-            pad = S_exec - R
-            xs[R:] = 0.0
-            seeds = list(seeds) + [0] * pad
-            offsets = list(offsets) + [0] * pad
-            dm = {m: np.concatenate(
-                [v, np.zeros((pad, n_nodes), v.dtype)])
-                for m, v in masks.items()}
-        if backend == "mesh":
-            # stage the batch pre-sharded over the dp axes: device_put
-            # to the executable's input sharding is one strided copy,
-            # while handing jit a replicated/device-0 array makes XLA
-            # reshard inside the program (measurably slower on a
-            # thread-starved host)
-            from jax.sharding import NamedSharding, PartitionSpec
-            xs_dev = jax.device_put(xs, NamedSharding(
-                self.mesh, PartitionSpec(None, self.dp_axes, None)))
-        else:
-            xs_dev = jnp.asarray(xs)
-        revealed = fn(
-            xs_dev,
-            jnp.asarray(seeds, dtype=jnp.uint32),
-            jnp.asarray(offsets, dtype=jnp.uint32),
-            {k: jnp.asarray(v) for k, v in dm.items()})
+        with span("svc.pack", unit=unit):
+            xs = self._buf_take((S_exec, n_nodes, padded))
+            r = 0
+            for s in sessions:
+                r += s.fill_payload_rows(xs, r, padded)
+            dm = masks
+            if S_exec > R:
+                # shape-bucket dispatch: dummy zero rows (zero payload,
+                # zero seed/offset, no faults) — batch rows are
+                # independent sessions, so the real rows' outputs are
+                # bit-identical and the dummies are sliced off after the
+                # sync
+                pad = S_exec - R
+                xs[R:] = 0.0
+                seeds = list(seeds) + [0] * pad
+                offsets = list(offsets) + [0] * pad
+                dm = {m: np.concatenate(
+                    [v, np.zeros((pad, n_nodes), v.dtype)])
+                    for m, v in masks.items()}
+        with span("svc.put", unit=unit):
+            if backend == "mesh":
+                # stage the batch pre-sharded over the dp axes:
+                # device_put to the executable's input sharding is one
+                # strided copy, while handing jit a replicated/device-0
+                # array makes XLA reshard inside the program (measurably
+                # slower on a thread-starved host)
+                from jax.sharding import NamedSharding, PartitionSpec
+                xs_dev = jax.device_put(xs, NamedSharding(
+                    self.mesh, PartitionSpec(None, self.dp_axes, None)))
+            else:
+                xs_dev = jnp.asarray(xs)
+            args = (xs_dev, jnp.asarray(seeds, dtype=jnp.uint32),
+                    jnp.asarray(offsets, dtype=jnp.uint32),
+                    {k: jnp.asarray(v) for k, v in dm.items()})
+        # jax.jit builds lazily: a cache miss compiles inside this call
+        with span("svc.compile" if fresh else "svc.issue", unit=unit):
+            revealed = fn(*args)
         return revealed, owner, fresh, R, masks, xs
 
     def _buf_take(self, shape) -> np.ndarray:
@@ -611,9 +619,10 @@ class BatchedExecutor:
         attempt stays retriable)."""
         t0 = self._clock()
         revealed, owner, fresh, R, masks, buf = self._dispatch(
-            sessions, padded, backend, fault)
-        revealed = np.asarray(revealed)[:R]      # host sync: span ends here
-        self._buf_give(buf)
+            sessions, padded, backend, fault, unit=unit)
+        with span("svc.settle", unit=unit):
+            revealed = np.asarray(revealed)[:R]  # host sync: span ends here
+            self._buf_give(buf)
         stage = "plan_compile" if fresh else "device_dispatch"
         self._h_stage[stage].observe(self._clock() - t0)
         self._account(sessions, padded, R, masks, unit, attempt, backend,
@@ -707,10 +716,10 @@ class BatchedExecutor:
                 if rec is not None:
                     rec.event("degrade", unit=salt, attempt=attempt,
                               sids=list(sids))
-            t1 = self._clock()
-            for i, s in enumerate(sessions):
-                s.reveal(revealed[owner == i].reshape(-1))
-            self._h_stage["reveal"].observe(self._clock() - t1)
+            with span("svc.reveal", self._h_stage["reveal"], self._clock,
+                      unit=salt):
+                for i, s in enumerate(sessions):
+                    s.reveal(revealed[owner == i].reshape(-1))
             self._c_batches.inc()
             self._c_sessions.inc(len(sessions))
             return None
@@ -873,7 +882,8 @@ class BatchedExecutor:
         try:
             (slot.revealed, slot.owner, slot.fresh, slot.rows,
              slot.masks, slot.buf) = self._dispatch(sessions, padded,
-                                                    backend, fault)
+                                                    backend, fault,
+                                                    unit=salt)
         except Exception as e:
             slot.error = e
         self._h_stage["pack_overlap"].observe(self._clock() - t0)
@@ -890,12 +900,12 @@ class BatchedExecutor:
         try:
             if slot.error is not None:
                 raise slot.error
-            t0 = self._clock()
-            revealed = np.asarray(slot.revealed)[:slot.rows]  # host sync
-            self._buf_give(slot.buf)
-            slot.buf = None
             stage = "plan_compile" if slot.fresh else "device_dispatch"
-            self._h_stage[stage].observe(self._clock() - t0)
+            with span("svc.settle", self._h_stage[stage], self._clock,
+                      unit=slot.unit):
+                revealed = np.asarray(slot.revealed)[:slot.rows]  # sync
+                self._buf_give(slot.buf)
+                slot.buf = None
             if (policy.deadline_s is not None
                     and time.monotonic() - slot.t_issue
                     > policy.deadline_s):
@@ -914,10 +924,10 @@ class BatchedExecutor:
             if rec is not None:
                 rec.event("degrade", unit=slot.unit, attempt=1,
                           sids=[s.sid for s in slot.sessions])
-        t1 = self._clock()
-        for i, s in enumerate(slot.sessions):
-            s.reveal(revealed[slot.owner == i].reshape(-1))
-        self._h_stage["reveal"].observe(self._clock() - t1)
+        with span("svc.reveal", self._h_stage["reveal"], self._clock,
+                  unit=slot.unit):
+            for i, s in enumerate(slot.sessions):
+                s.reveal(revealed[slot.owner == i].reshape(-1))
         self._c_batches.inc()
         self._c_sessions.inc(len(slot.sessions))
         return None
@@ -1162,7 +1172,12 @@ class AdmissionQueue:
         (a fully-poisoned batch, or a raising ``pre_execute``) is
         skipped for the rest of this pump, the sweep continues over the
         other keys, and the FIRST such error re-raises after the sweep
-        completes — one poisoned key never starves the rest."""
+        completes — one poisoned key never starves the rest.  The
+        whole call is the ``svc.pump`` span."""
+        with span("svc.pump"):
+            return self._pump(now, force)
+
+    def _pump(self, now: Optional[float], force: bool) -> int:
         now = time.monotonic() if now is None else now
         account_age = not force
         ran = 0

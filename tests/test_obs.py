@@ -1,5 +1,6 @@
 """Observability layer: metrics registry semantics, the trace flight
-recorder, and the exactness chain
+recorder, the service's spans and the engine's stage scopes on the
+profiler's clock, and the exactness chain
 
     round events  ==  batch event  ==  AggPlan.wire_bytes
                   ==  executed Transport.bytes_sent
@@ -8,18 +9,25 @@ recorder, and the exactness chain
 plus deterministic byte-identical JSONL replay under chaos (the
 obs-lane / chaos-lane anchor).
 """
+import collections
 import hashlib
+import re
+import warnings
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.engine import sim_batch
+from repro.core.engine import (STAGE_SCOPES, build_batch_executable,
+                               sim_batch)
 from repro.core.plan import (AggConfig, SessionMeta, compile_plan,
                              hop_wire_words)
 from repro.core.schedules import schedule_cost
 from repro.obs import (MetricsRegistry, SVC_STATS_DEPRECATED,
                        SVC_STATS_KEYS, SVC_STATS_VERSION, TickClock,
                        TraceRecorder, prometheus_text, stats_table)
+from repro.obs.spans import SERVICE_SPANS, span
 from repro.obs.trace import read_jsonl, to_jsonl
 from repro.runtime.chaos import ChaosConfig, ChaosError
 from repro.runtime.fault import SessionFaultPlan
@@ -95,17 +103,6 @@ def test_registry_labels_key_distinct_series():
         "q.flushes{reason=age}": 1, "q.flushes{reason=size}": 2}
 
 
-def test_disabled_registry_hands_out_noops():
-    reg = MetricsRegistry(enabled=False)
-    c = reg.counter("x")
-    c.inc(100)
-    reg.histogram("h").observe(1.0)
-    reg.gauge("g").set(5.0)
-    assert c.value == 0
-    assert reg.snapshot() == {"counters": {}, "gauges": {},
-                              "histograms": {}}
-
-
 def test_exporters_render_every_series():
     reg = MetricsRegistry()
     reg.counter("executor.batches_run").inc(3)
@@ -167,6 +164,170 @@ def test_hop_wire_words_matches_plan_and_schedule_cost(transport, backup):
                          digest_bytes=4 * cfg.digest_words,
                          digest_backup=backup)
     assert total == cost["bytes_total"]
+
+
+# ---------------------------------------------------------------------------
+# Spans and scopes on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def _profiled(tmp_path, body):
+    """Run ``body`` under the JAX profiler; returns the host plane's
+    events as (name, stats) pairs."""
+    import glob
+    from jax.profiler import ProfileData, ProfileOptions
+    opts = ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    with warnings.catch_warnings():
+        # jaxlib's stats iterator type warns that it has no __module__
+        warnings.simplefilter("ignore", DeprecationWarning)
+        return [(e.name, dict(e.stats))
+                for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU" for line in p.lines
+                for e in line.events]
+
+
+def test_span_observes_its_histogram_over_the_span_on_the_given_clock():
+    h = MetricsRegistry().histogram("stage.seconds", stage="reveal")
+    clock = TickClock(step=0.25)
+    with span("svc.reveal", h, clock, unit=3):
+        pass
+    with span("svc.pack", unit=3):              # no histogram: no clock
+        pass
+    assert (h.count, h.total) == (1, 0.25)
+    assert clock() == 0.5                      # read twice, by the first
+    with pytest.raises(RuntimeError):
+        with span("svc.reveal", h, clock):
+            raise RuntimeError("a failed step observes nothing")
+    assert h.count == 1
+
+
+def test_span_shows_on_the_host_plane_of_a_cpu_profiler_trace(tmp_path):
+    h = MetricsRegistry().histogram("stage.seconds", stage="reveal")
+
+    def body():
+        with span("svc.reveal", h, unit=7):
+            jnp.ones(8).block_until_ready()
+    events = _profiled(tmp_path, body)
+    assert ("svc.reveal", {"unit": 7}) in events
+    assert h.count == 1 and h.total > 0
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_service_steps_are_spans_of_their_retry_unit(tmp_path, depth):
+    """Every host step of a batch is a span carrying the batch's retry
+    unit, the id the recorder's ``batch`` event carries; the first
+    batch of a shape compiles, the next issues; one ``svc.seal`` per
+    session."""
+    from repro.service import StreamConfig
+    rec = TraceRecorder(clock=TickClock())
+    svc = AggregationService(_params(), recorder=rec,
+                             batching=BatchingConfig(max_batch=2,
+                                                     max_age=1e9),
+                             stream=StreamConfig(depth=depth))
+    vals = _vals(4)
+
+    def body():
+        for i in range(4):
+            s = svc.open(now=0.0)
+            for slot in range(N):
+                s.contribute(slot, vals[i, slot])
+            svc.seal(s.sid, now=0.0)
+        svc.pump(now=1.0)
+    events = _profiled(tmp_path, body)
+    names = collections.Counter(n for n, _ in events
+                                if n.startswith("svc."))
+    assert set(names) == set(SERVICE_SPANS)
+    assert names["svc.seal"] == 4 and names["svc.pump"] == 1
+    assert names["svc.compile"] == names["svc.issue"] == 1
+    units = {e["unit"] for e in rec.events("batch")}
+    for step in ("svc.pack", "svc.put", "svc.settle", "svc.reveal"):
+        assert names[step] == 2
+        assert {st["unit"] for n, st in events if n == step} == units
+    sids = {st["sid"] for n, st in events if n == "svc.seal"}
+    assert sids == {0, 1, 2, 3}
+
+
+def _entry_ops(fn, S, n, T):
+    """(opcode, name, op_name) of every instruction of the lowered
+    program's entry computation."""
+    low = fn.lower(jax.ShapeDtypeStruct((S, n, T), jnp.float32),
+                   jax.ShapeDtypeStruct((S,), jnp.uint32),
+                   jax.ShapeDtypeStruct((S,), jnp.uint32), {})
+    text = low.compiler_ir("hlo").as_hlo_module().to_string()
+    entry = text[text.index("\nENTRY"):]
+    ops = []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%?(\S+) = \S+ ([\w-]+)\(", line)
+        if m:
+            on = re.search(r'op_name="([^"]*)"', line)
+            ops.append((m.group(2), m.group(1), on.group(1) if on else ""))
+    return ops, text
+
+
+@pytest.mark.parametrize("impl,transport", [
+    ("jnp", "full"), ("jnp", "digest"), ("pallas_interpret", "full")])
+def test_every_op_of_the_batch_executable_carries_a_stage_scope(
+        impl, transport):
+    """In the lowered batch executable every traced op of the entry
+    computation sits under an ``agg.*`` scope (calls into nested jits
+    included, whose ops take the caller's scope when XLA inlines them),
+    save the parameters and the entry reshape/convert of ``xs``;
+    constants, and their broadcasts, carry no op_name at all.  Every
+    stage scope, and a round's, appears."""
+    cfg = AggConfig(n_nodes=16, cluster_size=4, redundancy=3,
+                    transport=transport, kernel_impl=impl)
+    fn = build_batch_executable(compile_plan(cfg), impl=impl)
+    ops, text = _entry_ops(fn, 2, 16, 256)
+    outside = [(code, on) for code, name, on in ops if "agg." not in on]
+    for code, on in outside:
+        if on:
+            assert code == "parameter" or (
+                code in ("reshape", "convert")
+                and on.startswith("jit(raw)/")
+                and on.count("/") == 1), (code, on)
+        else:
+            assert code in ("constant", "broadcast"), code
+    assert sum(bool(on) for _, on in outside) <= 5
+    for scope in STAGE_SCOPES + ("agg.round_0",):
+        assert f"/{scope}/" in text, scope
+
+
+def test_stage_scopes_leave_the_compiled_program_unchanged(monkeypatch):
+    """The scopes change metadata only: the optimized program compiled
+    with and without them is the same once metadata and instruction
+    names are taken out."""
+    import contextlib
+    cfg = AggConfig(n_nodes=16, cluster_size=4, redundancy=3)
+    args = (jax.ShapeDtypeStruct((1, 16, 512), jnp.float32),
+            jax.ShapeDtypeStruct((1,), jnp.uint32),
+            jax.ShapeDtypeStruct((1,), jnp.uint32), {})
+
+    def program():
+        """(whether any op is scoped, the program without metadata)"""
+        fn = build_batch_executable(compile_plan(cfg))
+        text = fn.lower(*args).compile().as_text()
+        scoped = "/agg.hop/" in text
+        # the source-file and stack-frame tables, then each op's metadata
+        head, _, rest = text.partition("\nFileNames\n")
+        text = head + rest[re.search(r"^(%|ENTRY)", rest, re.M).start():]
+        text = re.sub(r", metadata=\{[^}]*\}", "", text)
+        names: dict = {}
+        return scoped, re.sub(
+            r"%[\w.\-]+",
+            lambda m: names.setdefault(m.group(0), f"%v{len(names)}"), text)
+    with_scopes, program_a = program()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without, program_b = program()
+    assert with_scopes and not without
+    assert program_a == program_b
 
 
 # ---------------------------------------------------------------------------
