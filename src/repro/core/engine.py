@@ -29,7 +29,9 @@ equivocation — so digest-specific adversaries (equivocation,
 digest/payload mismatch, crash-at-hop-k) are modeled identically by the
 oracle and the mesh.  Every hop also feeds ``Transport.bytes_sent``, a
 trace-time bandwidth account the conformance tests pin against
-``schedules.schedule_cost``.
+``schedules.schedule_cost``, and every full-transport vote feeds
+``Transport.vote_calls``, a trace-time tally of the votes by the layout
+the kernel dispatch picks for their shape (``vote_layout``).
 
 The value container is uniform: every chunk is a ``(rows, T)`` array
 where ``rows = S`` sessions times the transport's local node slots (all
@@ -54,9 +56,9 @@ from repro.core.byzantine import (digest_rows, digest_vote_combine,
 from repro.core.plan import (AggPlan, HopRound, SessionMeta, compile_plan,
                              hop_wire_words)
 from repro.kernels import backend
-from repro.kernels.secure_agg import (mask_encrypt_batch_fn,
+from repro.kernels.secure_agg import (VOTE_LAYOUTS, mask_encrypt_batch_fn,
                                       unmask_decrypt_batch_fn,
-                                      vote_combine_batch_fn)
+                                      vote_combine_batch_fn, vote_layout)
 
 _ENC_MODE = {"global": "mask", "pairwise": "pairwise", "none": "quantize"}
 
@@ -97,6 +99,9 @@ class Transport:
     # bytes this transport instance has shipped across hops (trace-time
     # account over the plan's static pair lists; see ``_account``)
     bytes_sent: int = 0
+    # full-transport vote calls this instance has traced, by the layout
+    # the kernel dispatch picks ("rows" or "flat"; see ``vote``)
+    vote_calls: dict
     _static_faults: Optional[list] = None
 
     def _fault_items(self, meta: SessionMeta) -> list:
@@ -214,6 +219,7 @@ class Transport:
     def vote(self, rnd: HopRound, inflight, base: jax.Array) -> jax.Array:
         """base + majority(inflight) — one fused pass per transport."""
         if self.plan.cfg.transport == "full":
+            self.vote_calls[vote_layout(base.shape)] += 1
             return vote_combine_batch_fn(inflight, base, impl=self.impl)
         payload, dg_copies, backup = inflight
         return digest_vote_combine(payload, dg_copies, base, backup=backup,
@@ -397,7 +403,8 @@ def sim_batch(plan: AggPlan, xs: jax.Array, meta: SessionMeta, *,
 def build_batch_executable(plan: AggPlan, *, backend: str = "sim",
                            mesh=None, dp_axes: Sequence[str] = ("data",),
                            impl: Optional[str] = None,
-                           donate: bool = False):
+                           donate: bool = False,
+                           vote_calls: Optional[dict] = None):
     """The one jitted batch-reveal executable the service executor and
     the facade's batched one-shot share:
 
@@ -412,14 +419,25 @@ def build_batch_executable(plan: AggPlan, *, backend: str = "sim",
     streaming executor's double-buffered slots exist exactly so packing
     the next slot never touches a donated buffer).  Donation is a
     no-op (with a UserWarning) on the CPU backend, so callers gate it
-    on ``jax.default_backend()``."""
+    on ``jax.default_backend()``.
+
+    ``vote_calls``, where given, is a dict that every trace of the
+    executable refills with its transport's ``vote_calls`` tally (of
+    the one program; on a mesh, of each rank's)."""
+    def traced(tally: dict) -> None:
+        if vote_calls is not None:
+            vote_calls.clear()
+            vote_calls.update(tally)
+
     if backend == "mesh":
         mt = MeshTransport(mesh, dp_axes, impl=impl)
 
         def raw(xs, seeds, offsets, fault_masks):
             meta = SessionMeta(seeds=seeds, offsets=offsets,
                                fault_masks=fault_masks)
-            return mt.execute(plan, xs, meta, reveal_only=True)
+            out = mt.execute(plan, xs, meta, reveal_only=True)
+            traced(mt.last_vote_calls)
+            return out
     else:
         def raw(xs, seeds, offsets, fault_masks):
             meta = SessionMeta(seeds=seeds, offsets=offsets,
@@ -429,6 +447,7 @@ def build_batch_executable(plan: AggPlan, *, backend: str = "sim",
             flat = xs.reshape(S * n, T).astype(jnp.float32)
             (out,) = execute_chunks(plan, tp, [flat], meta,
                                     reveal_only=True)
+            traced(tp.vote_calls)
             return out
 
     return jax.jit(raw, donate_argnums=(0,) if donate else ())
@@ -479,6 +498,7 @@ class SimTransport(Transport):
         self.plan = plan
         self.S = S
         self.bytes_sent = 0
+        self.vote_calls = dict.fromkeys(VOTE_LAYOUTS, 0)
         self.impl = backend.resolve(
             impl if impl is not None else plan.cfg.kernel_impl)
 
@@ -577,6 +597,7 @@ class ManualTransport(Transport):
         self.dp_axes = tuple(dp_axes)
         self.S = S
         self.bytes_sent = 0
+        self.vote_calls = dict.fromkeys(VOTE_LAYOUTS, 0)
         self.impl = backend.resolve(
             impl if impl is not None else plan.cfg.kernel_impl)
         # distributed reveal: each rank decrypts only its 1/n slice of
@@ -656,8 +677,9 @@ class MeshTransport:
     sealed service batch runs the *same* engine code the oracle runs,
     over real collectives.  Bit-identical to ``SimTransport`` for the
     same plan (pinned by tests/test_engine.py and the conformance grid
-    on a forced-8-device host).  ``last_bytes`` holds the inner
-    transport's bandwidth account after a (re)traced ``execute``."""
+    on a forced-8-device host).  ``last_bytes`` and ``last_vote_calls``
+    hold the inner transport's bandwidth account and vote tally after a
+    (re)traced ``execute``."""
 
     def __init__(self, mesh: jax.sharding.Mesh,
                  dp_axes: Sequence[str] = ("data",),
@@ -670,6 +692,7 @@ class MeshTransport:
         # raise-at-hop-k fault); must preserve the Transport protocol
         self.wrap_inner = wrap_inner
         self.last_bytes: Optional[int] = None
+        self.last_vote_calls: Optional[dict] = None
         n = 1
         for ax in self.dp_axes:
             n *= mesh.shape[ax]
@@ -719,4 +742,5 @@ class MeshTransport:
                  dict(meta.fault_masks))
         if inner:
             self.last_bytes = inner[-1].bytes_sent
+            self.last_vote_calls = dict(inner[-1].vote_calls)
         return out[:S] if reveal_only else out

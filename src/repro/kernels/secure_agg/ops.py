@@ -17,11 +17,16 @@ from jax.sharding import PartitionSpec as P
 
 from repro.kernels import backend
 from repro.kernels.secure_agg import ref as R
-from repro.kernels.secure_agg.secure_agg import (mask_encrypt,
+from repro.kernels.secure_agg.secure_agg import (LANES, SUBLANES,
+                                                 mask_encrypt,
                                                  mask_encrypt_batch,
                                                  unmask_decrypt,
                                                  unmask_decrypt_batch,
-                                                 vote_combine)
+                                                 vote_combine,
+                                                 vote_combine_rows)
+
+# The two layouts ``vote_combine_batch_fn`` votes a (rows, T) batch in.
+VOTE_LAYOUTS = ("rows", "flat")
 
 
 def _interp(impl: str) -> bool:
@@ -125,14 +130,31 @@ def unmask_decrypt_batch_fn(agg, n_nodes: int, seeds, scale: float,
                    mode=mode, offsets=offsets, interpret=_interp(impl))
 
 
+def vote_layout(shape: tuple) -> str:
+    """The layout ``vote_combine_batch_fn`` votes a ``shape`` batch in:
+    ``"rows"`` where the (rows, T) operands hold at least one whole
+    (8, 128) tile, else ``"flat"``."""
+    rows, T = shape
+    return "rows" if rows >= SUBLANES and T >= LANES else "flat"
+
+
 def vote_combine_batch_fn(copies: Sequence[jax.Array], acc,
                           impl: Optional[str] = None) -> jax.Array:
-    """acc + majority(copies) over (B, T) rows — the vote is elementwise,
-    so the batch flattens into one call of the flat kernel (bit-identical
-    to voting each row separately)."""
-    copies = [c.reshape(-1) for c in R.as_copy_list(copies)]
-    return vote_combine_fn(copies, acc.reshape(-1),
-                           impl=impl).reshape(acc.shape)
+    """acc + majority(copies) over (rows, T) rows, bit-identical to
+    voting each row separately (the vote is elementwise).  The layout
+    follows the shape (``vote_layout``): ``"rows"`` votes the operands
+    as they are, with ``vote_combine_rows`` (the jnp engine votes them
+    elementwise); ``"flat"`` flattens the batch into one call of the
+    flat kernel."""
+    copies = R.as_copy_list(copies)
+    if vote_layout(acc.shape) == "flat":
+        return vote_combine_fn([c.reshape(-1) for c in copies],
+                               acc.reshape(-1),
+                               impl=impl).reshape(acc.shape)
+    impl = backend.resolve(impl)
+    if impl == "jnp":
+        return R.vote_combine_ref(copies, acc)
+    return _kernel(vote_combine_rows, copies, acc, interpret=_interp(impl))
 
 
 @functools.partial(jax.jit,

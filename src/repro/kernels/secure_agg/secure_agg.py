@@ -14,10 +14,17 @@ DESIGN §2.2) — one fused VMEM pass per protocol stage:
     Copies arrive as r *separate* operands so no (r, T) buffer is ever
     materialized by the caller.
 
-All kernels use (8, 128)-aligned 2-D tiles (the float32/uint32 VPU tile)
-so they compile natively on TPU; arbitrary flat lengths are handled by
-internal padding + a final slice.  ``interpret=None`` defers to
-``repro.kernels.backend`` (native on TPU, interpreter elsewhere).
+  * ``vote_combine_rows`` — the same vote over ``(rows, T)`` operands in
+    their own layout: one ``(tb, tt)`` block per grid step, ragged edge
+    blocks masked by Pallas, so a batch of rows is voted with no
+    relayout into flat tiles and back.
+
+Blocks are (8, 128)-aligned (the float32/uint32 VPU tile) so every kernel
+compiles natively on TPU.  The flat kernels view a flat length as
+``(rows, 128)`` tiles, with internal padding and a final slice for
+arbitrary lengths; ``vote_combine_rows`` needs neither.
+``interpret=None`` defers to ``repro.kernels.backend`` (native on TPU,
+interpreter elsewhere).
 """
 from __future__ import annotations
 
@@ -421,3 +428,52 @@ def vote_combine(copies: Union[jax.Array, Sequence[jax.Array]],
         interpret=backend.interpret_default(interpret),
     )(*[_to_tiles(c, rows_p) for c in copies], _to_tiles(acc, rows_p))
     return out.reshape(-1)[:T]
+
+
+# ---------------------------------------------------------------------------
+# vote_combine_rows: the same vote over (rows, T) operands as they are
+# ---------------------------------------------------------------------------
+
+# The r + 2 operand blocks (r copies, the accumulator and the result) are
+# double-buffered in the 16 MiB of scoped VMEM: (256, 1024) uint32 blocks
+# at r = 3 take 10 MiB, (256, 2048) ones 20 MiB and do not compile.
+VOTE_VMEM_BUDGET = 12 << 20
+VOTE_BLOCK_ROWS = 256
+VOTE_BLOCK_ELEMS = 1 << 18          # 1 MiB of uint32 per operand block
+
+
+def _vote_rows_block(rows: int, T: int, r: int) -> tuple[int, int]:
+    """(tb, tt) block of the (rows, T) vote over r copies: tb a multiple
+    of 8 (``rows >= 8``), tt a power of two of at least 128 or all of T,
+    so that the r + 2 double-buffered operand blocks fit
+    ``VOTE_VMEM_BUDGET``."""
+    tb = min(VOTE_BLOCK_ROWS, rows // SUBLANES * SUBLANES)
+    elems = min(VOTE_BLOCK_ELEMS, VOTE_VMEM_BUDGET // (2 * (r + 2) * 4))
+    tt = max(LANES, 1 << ((elems // tb).bit_length() - 1))
+    return tb, min(tt, T)
+
+
+def vote_combine_rows(copies: Sequence[jax.Array], acc: jax.Array, *,
+                      interpret: Optional[bool] = None) -> jax.Array:
+    """acc + elementwise-majority(copies) over r separate (rows, T)
+    uint32 operands (``rows >= 8``, r odd), voted in their own layout:
+    one ``(tb, tt)`` block per grid step over ``(cdiv(rows, tb),
+    cdiv(T, tt))``.  Pallas masks the ragged edge blocks, so there is no
+    padding and no final slice: on the TPU the operands keep their
+    (8, 128) tiling over (rows, T) and no relayout is made on the way in
+    or out.  Bit-identical to ``vote_combine`` of the flattened
+    operands."""
+    r = len(copies)
+    assert r % 2 == 1, "vote redundancy must be odd"
+    rows, T = acc.shape
+    tb, tt = _vote_rows_block(rows, T, r)
+    spec = pl.BlockSpec((tb, tt), lambda i, j: (i, j))
+    return pl.pallas_call(
+        functools.partial(_vote_kernel, r=r),
+        grid=(pl.cdiv(rows, tb), pl.cdiv(T, tt)),
+        in_specs=[spec] * (r + 1),
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((rows, T), jnp.uint32),
+        name="vote_combine",
+        interpret=backend.interpret_default(interpret),
+    )(*copies, acc)

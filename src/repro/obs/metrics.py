@@ -51,6 +51,9 @@ M_QUARANTINED = "executor.quarantined"
 M_DEADLINE_HITS = "executor.deadline_hits"
 M_DEGRADED = "executor.degraded_batches"
 M_WIRE_BYTES = "executor.wire_bytes"          # modeled == engine account
+# full-transport vote calls run, labeled layout=rows|flat: the batch
+# executables' trace-time ``Transport.vote_calls`` tallies, per batch
+M_VOTE_CALLS = "executor.vote_calls"
 # streaming pipeline: high-watermark of concurrently in-flight batch
 # slots (1 = sequential; == StreamConfig.depth when overlap happened)
 G_PIPELINE_DEPTH = "executor.pipeline_depth"

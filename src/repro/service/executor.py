@@ -96,6 +96,7 @@ from repro.core.engine import (MeshTransport, SimTransport,
                                build_batch_executable, execute_chunks)
 from repro.core.plan import (SessionMeta, compile_plan, fault_masks_of,
                              _require)
+from repro.kernels.secure_agg import VOTE_LAYOUTS
 from repro.obs import metrics as M
 from repro.obs.spans import span
 from repro.obs.trace import TraceRecorder, record_batch_trace
@@ -225,7 +226,7 @@ class _Slot:
 
     __slots__ = ("sessions", "padded", "unit", "backend", "degraded",
                  "revealed", "owner", "fresh", "rows", "masks",
-                 "t_issue", "error", "buf")
+                 "t_issue", "error", "buf", "votes")
 
     def __init__(self, sessions, padded, unit, backend, degraded):
         self.sessions = sessions
@@ -241,6 +242,7 @@ class _Slot:
         self.t_issue = 0.0
         self.error: Optional[Exception] = None
         self.buf = None               # pack buffer, recycled at settle
+        self.votes = None             # the executable's vote tally
 
 
 class BatchedExecutor:
@@ -322,11 +324,16 @@ class BatchedExecutor:
         self._c_deadline = m.counter(M.M_DEADLINE_HITS)
         self._c_degraded = m.counter(M.M_DEGRADED)
         self._c_wire = m.counter(M.M_WIRE_BYTES)
+        self._c_votes = {layout: m.counter(M.M_VOTE_CALLS, layout=layout)
+                         for layout in VOTE_LAYOUTS}
         self._h_stage = {s: m.histogram(M.H_STAGE, stage=s)
                          for s in M.STAGES}
         self.dead_letter: list[tuple[int, str]] = []   # (sid, error repr)
         self._units = 0               # retry units started (jitter salt)
         self._plans: dict = {}        # params -> AggPlan (byte account)
+        # executable key -> the vote tally its trace fills (see
+        # ``build_batch_executable(vote_calls=...)``)
+        self._votes: dict = {}
 
     def _plan_of(self, template: Session):
         """Compiled plan of one batch's shared params (hot-path memo in
@@ -407,14 +414,16 @@ class BatchedExecutor:
                         if self.breaker is not None else None),
         }
 
-    def _build_fn(self, template: Session, backend: str):
+    def _build_fn(self, template: Session, backend: str, key):
         """The shared jitted batch executable (see
         ``core.engine.build_batch_executable``) with the executor's
-        donation policy applied."""
+        donation policy applied; its trace fills the vote tally of
+        executable ``key``."""
         plan = self._plan_of(template)
         return build_batch_executable(
             plan, backend=backend, mesh=self.mesh, dp_axes=self.dp_axes,
-            impl=self.kernel_impl, donate=self._donate)
+            impl=self.kernel_impl, donate=self._donate,
+            vote_calls=self._votes.setdefault(key, {}))
 
     def _drain_warmed(self) -> None:
         """Promote finished background AOT compiles into the cache (a
@@ -439,7 +448,7 @@ class BatchedExecutor:
         if self._pool is None:
             self._pool = concurrent.futures.ThreadPoolExecutor(
                 max_workers=1)
-        fn = self._build_fn(template, backend)
+        fn = self._build_fn(template, backend, key)
         n = template.params.n_nodes
         f32, u32 = jnp.float32, jnp.uint32
 
@@ -456,15 +465,17 @@ class BatchedExecutor:
 
     def _compiled(self, template: Session, padded: int, S: int,
                   modes: frozenset,
-                  backend: str) -> tuple[Callable, bool, int]:
-        """(executable, fresh, S_exec) — ``fresh`` marks a synchronous
-        cache miss, which the stage timer attributes to ``plan_compile``
-        (jax.jit is lazy, so the XLA build cost lands on the miss's
-        first dispatch).  ``S_exec >= S`` is the row count the returned
-        executable was compiled for: on a miss with ``async_compile``
-        the exact shape warms in the background and the dispatch runs
-        on the smallest already-compiled larger-S bucket (the caller
-        pads with dummy rows and slices the first S back out)."""
+                  backend: str) -> tuple[Callable, bool, int, dict]:
+        """(executable, fresh, S_exec, votes) — ``fresh`` marks a
+        synchronous cache miss, which the stage timer attributes to
+        ``plan_compile`` (jax.jit is lazy, so the XLA build cost lands on
+        the miss's first dispatch).  ``S_exec >= S`` is the row count the
+        returned executable was compiled for: on a miss with
+        ``async_compile`` the exact shape warms in the background and the
+        dispatch runs on the smallest already-compiled larger-S bucket
+        (the caller pads with dummy rows and slices the first S back
+        out).  ``votes`` is the returned executable's vote tally, filled
+        by its trace."""
         # fault PATTERNS are runtime (S, n) masks, so churn/missing-slot
         # variation never retraces; only the set of fault MODES present
         # (<= 8 combinations) and the dispatch backend are part of the
@@ -476,7 +487,7 @@ class BatchedExecutor:
         fn = self._fns.get(key)
         if fn is not None:
             self._c_fn_hits.inc()
-            return fn, False, S
+            return fn, False, S, self._votes[key]
         self._c_fn_misses.inc()
         if self.stream.async_compile and self.stream.depth > 1:
             buckets = [k[1] for k in self._fns
@@ -486,23 +497,26 @@ class BatchedExecutor:
                 self._c_fn_bucket.inc()
                 self._warm_async(key, template, padded, S, modes, backend)
                 S_exec = min(buckets)
-                return (self._fns[(bk, S_exec, modes, backend)],
-                        False, S_exec)
-        fn = self._build_fn(template, backend)
+                exec_key = (bk, S_exec, modes, backend)
+                return (self._fns[exec_key], False, S_exec,
+                        self._votes[exec_key])
+        fn = self._build_fn(template, backend, key)
         self._fns[key] = fn
-        return fn, True, S
+        return fn, True, S, self._votes[key]
 
     # -- one dispatch attempt ----------------------------------------------
     def _dispatch(self, sessions: Sequence[Session], padded: int,
                   backend: str, fault: Optional[ChaosConfig], unit: int):
         """Pack + issue one batch WITHOUT the host sync: returns
-        ``(revealed, owner, fresh, rows, masks)`` where ``revealed`` is
-        the (possibly still in-flight) device result of the first
-        ``rows`` real rows (bucketed dispatches pad with dummy rows —
-        the caller slices ``[:rows]`` after its ``np.asarray`` sync) and
-        ``masks`` are the real rows' fault masks (what the trace
-        records).  Session state is untouched, so a failed attempt
-        stays retriable.  The pack, the host-to-device copy and the call
+        ``(revealed, owner, fresh, rows, masks, buf, votes)`` where
+        ``revealed`` is the (possibly still in-flight) device result of
+        the first ``rows`` real rows (bucketed dispatches pad with dummy
+        rows — the caller slices ``[:rows]`` after its ``np.asarray``
+        sync), ``masks`` are the real rows' fault masks (what the trace
+        records), ``buf`` is the pack buffer to recycle at settlement and
+        ``votes`` the executable's vote tally (None for the eager chaos
+        run).  Session state is untouched, so a failed attempt stays
+        retriable.  The pack, the host-to-device copy and the call
         are the ``svc.pack`` / ``svc.put`` / ``svc.issue`` (or, on a
         cache miss, ``svc.compile``) spans of retry unit ``unit``."""
         if fault is not None and fault.mode == "dispatch":
@@ -531,9 +545,9 @@ class BatchedExecutor:
                            for mat in s.payload_rows(padded)])
             revealed = self._chaos_hop_run(sessions[0], xs, seeds, offsets,
                                            masks, backend, fault)
-            return revealed, owner, fresh, R, masks, None
-        fn, fresh, S_exec = self._compiled(sessions[0], padded, R,
-                                           frozenset(masks), backend)
+            return revealed, owner, fresh, R, masks, None, None
+        fn, fresh, S_exec, votes = self._compiled(
+            sessions[0], padded, R, frozenset(masks), backend)
         # pack straight into a recycled (S_exec, n, padded) slot buffer
         # — fill_payload_rows writes every byte of the real rows, so no
         # pre-zeroing; the buffer returns to the pool once this batch
@@ -575,7 +589,7 @@ class BatchedExecutor:
         # jax.jit builds lazily: a cache miss compiles inside this call
         with span("svc.compile" if fresh else "svc.issue", unit=unit):
             revealed = fn(*args)
-        return revealed, owner, fresh, R, masks, xs
+        return revealed, owner, fresh, R, masks, xs, votes
 
     def _buf_take(self, shape) -> np.ndarray:
         """A pooled float32 pack buffer (fresh if the pool is dry)."""
@@ -597,13 +611,16 @@ class BatchedExecutor:
 
     def _account(self, sessions: Sequence[Session], padded: int, rows: int,
                  masks: dict, unit: int, attempt: int, backend: str,
-                 fresh: bool) -> None:
-        """Book one completed attempt's wire bytes and flight-recorder
-        events — all host-side, after the device sync, so the jitted
-        program is untouched.  The streaming path defers this to slot
-        settlement (the account describes an execution that finished)."""
+                 fresh: bool, votes: Optional[dict]) -> None:
+        """Book one completed attempt's wire bytes, vote calls by layout
+        (the executable's trace-time tally) and flight-recorder events —
+        all host-side, after the device sync, so the jitted program is
+        untouched.  The streaming path defers this to slot settlement
+        (the account describes an execution that finished)."""
         plan = self._plan_of(sessions[0])
         self._c_wire.inc(plan.wire_bytes(padded, S=rows))
+        for layout, calls in (votes or {}).items():
+            self._c_votes[layout].inc(calls)
         if self.recorder is not None:
             record_batch_trace(
                 self.recorder, plan, padded=padded, rows=rows,
@@ -618,7 +635,7 @@ class BatchedExecutor:
         caller reveals after the deadline check, so a failed/too-slow
         attempt stays retriable)."""
         t0 = self._clock()
-        revealed, owner, fresh, R, masks, buf = self._dispatch(
+        revealed, owner, fresh, R, masks, buf, votes = self._dispatch(
             sessions, padded, backend, fault, unit=unit)
         with span("svc.settle", unit=unit):
             revealed = np.asarray(revealed)[:R]  # host sync: span ends here
@@ -626,7 +643,7 @@ class BatchedExecutor:
         stage = "plan_compile" if fresh else "device_dispatch"
         self._h_stage[stage].observe(self._clock() - t0)
         self._account(sessions, padded, R, masks, unit, attempt, backend,
-                      fresh)
+                      fresh, votes)
         return revealed, owner
 
     def _chaos_hop_run(self, template: Session, xs, seeds, offsets, masks,
@@ -881,9 +898,8 @@ class BatchedExecutor:
         t0 = self._clock()
         try:
             (slot.revealed, slot.owner, slot.fresh, slot.rows,
-             slot.masks, slot.buf) = self._dispatch(sessions, padded,
-                                                    backend, fault,
-                                                    unit=salt)
+             slot.masks, slot.buf, slot.votes) = self._dispatch(
+                sessions, padded, backend, fault, unit=salt)
         except Exception as e:
             slot.error = e
         self._h_stage["pack_overlap"].observe(self._clock() - t0)
@@ -917,7 +933,7 @@ class BatchedExecutor:
             self._record_breaker(rec, slot.backend, failed=True)
             return e
         self._account(slot.sessions, slot.padded, slot.rows, slot.masks,
-                      slot.unit, 1, slot.backend, slot.fresh)
+                      slot.unit, 1, slot.backend, slot.fresh, slot.votes)
         self._record_breaker(rec, slot.backend, failed=False)
         if slot.degraded:
             self._c_degraded.inc()

@@ -431,6 +431,66 @@ def test_queue_protection_events_shed_and_expire():
     assert svc.get(3).state is SessionState.EXPIRED
 
 
+def _vote_counters(metrics: MetricsRegistry) -> dict:
+    counters = metrics.snapshot()["counters"]
+    return {layout: counters[f"executor.vote_calls{{layout={layout}}}"]
+            for layout in ("rows", "flat")}
+
+
+def test_executor_counts_vote_calls_by_layout():
+    """Each executed batch adds its executable's trace-time vote tally
+    to ``executor.vote_calls``: a committee-256 session (n = 256, ring,
+    one session a batch) votes its 63 rounds on the (256, T) rows as
+    they are; a batch under one (8, 128) tile is flattened."""
+    n, T, sessions = 256, 128, 2
+    params = SessionParams(n_nodes=n, elems=T, cluster_size=4,
+                           redundancy=3)
+    vals = RNG.normal(size=(sessions, n, T)).astype(np.float32) * 0.3
+    svc = AggregationService(params, batching=BatchingConfig(max_batch=1,
+                                                             max_age=1e9))
+    for i in range(sessions):
+        s = svc.open(now=0.0)
+        for slot in range(n):
+            s.contribute(slot, vals[i, slot])
+        svc.seal(s.sid, now=0.0)
+    svc.drain()
+    assert len(compile_plan(params.agg_config()).rounds) == 63
+    assert svc.stats["batches"]["run"] == sessions
+    assert _vote_counters(svc.metrics) == {"rows": 63 * sessions, "flat": 0}
+    # N = 8 slots of ELEMS = 16: one batch, under a whole tile
+    small = _service(S=1, vals=_vals(1))
+    small.drain()
+    rounds = len(compile_plan(_params().agg_config()).rounds)
+    assert _vote_counters(small.metrics) == {"rows": 0, "flat": rounds}
+
+
+def test_train_step_sync_votes_flat(monkeypatch):
+    """The secure train step's sync (``tree_allreduce`` inside a
+    shard_map manual over the dp axis) votes one row a rank, which the
+    layout rule flattens into the flat kernel's tiles."""
+    from jax.sharding import AbstractMesh, PartitionSpec as P
+    from repro.core import engine
+    made = []
+
+    class Recording(engine.ManualTransport):
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            made.append(self)
+
+    monkeypatch.setattr(engine, "ManualTransport", Recording)
+    # c = 1: a grouped psum cannot be traced over an abstract mesh
+    cfg = AggConfig(n_nodes=4, cluster_size=1, redundancy=1)
+    sync = jax.shard_map(
+        lambda g: engine.tree_allreduce({"w": g}, cfg, ("data",))["w"],
+        mesh=AbstractMesh((4,), ("data",)), in_specs=P("data"),
+        out_specs=P("data"))
+    jax.jit(sync).trace(jax.ShapeDtypeStruct((4 * 4096,), jnp.float32))
+    (tp,) = made
+    rounds = len(compile_plan(cfg).rounds)
+    assert rounds > 0
+    assert tp.vote_calls == {"rows": 0, "flat": rounds}
+
+
 # ---------------------------------------------------------------------------
 # svc.stats schema: canonical nested keys + deprecated aliases
 # ---------------------------------------------------------------------------

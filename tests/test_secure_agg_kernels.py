@@ -24,8 +24,9 @@ from repro.kernels.secure_agg import (mask_encrypt_batch_op, mask_encrypt_op,
                                       mask_encrypt_ref,
                                       unmask_decrypt_batch_op,
                                       unmask_decrypt_op, unmask_decrypt_ref,
+                                      vote_combine_batch_fn,
                                       vote_combine_batch_op, vote_combine_op,
-                                      vote_combine_ref)
+                                      vote_combine_ref, vote_layout)
 
 PALLAS = backend.pallas_impl()
 RNG = np.random.default_rng(7)
@@ -237,9 +238,27 @@ def test_unmask_decrypt_batch_matches_per_row(T, mode):
         assert bool(jnp.all(got == want)), impl
 
 
-@pytest.mark.parametrize("r", [1, 3])
-def test_vote_combine_batch_matches_per_row(r):
-    B, T = 4, 129
+def _pallas_out_shapes(fn, *args) -> list:
+    """Result shapes of the Pallas calls in ``fn``'s jaxpr."""
+    eqns = jax.make_jaxpr(fn)(*args).jaxpr.eqns
+    return [e.outvars[0].aval.shape for e in eqns
+            if e.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("B,T,r,layout", [
+    (4, 129, 1, "flat"), (4, 129, 3, "flat"), (4, 1000, 5, "flat"),
+    (256, 64, 3, "flat"), (12, 64, 1, "flat"),
+    (8, 129, 3, "rows"), (8, 1000, 5, "rows"), (8, 65536, 1, "rows"),
+    (12, 129, 1, "rows"), (12, 1000, 3, "rows"), (12, 65536, 5, "rows"),
+    (256, 1000, 5, "rows"), (256, 65536, 3, "rows"),
+    (8, 40000, 3, "rows"),                      # ragged T block
+])
+def test_vote_combine_batch_matches_per_row(B, T, r, layout):
+    """The batched vote equals the per-row jnp vote, bit for bit, in
+    either layout; the layout is the shape rule's (at least one whole
+    (8, 128) tile votes the rows as they are, 12 rows leave a ragged row
+    block), and the Pallas call it makes has the batch's own shape only
+    in the ``rows`` layout."""
     copies = [jnp.asarray(RNG.integers(0, 2 ** 32, (B, T), dtype=np.uint32))
               for _ in range(r)]
     acc = jnp.asarray(RNG.integers(0, 2 ** 32, (B, T), dtype=np.uint32))
@@ -249,6 +268,10 @@ def test_vote_combine_batch_matches_per_row(r):
     for impl in (PALLAS, "jnp"):
         got = vote_combine_batch_op(tuple(copies), acc, impl=impl)
         assert bool(jnp.all(got == want)), impl
+    assert vote_layout((B, T)) == layout
+    (shape,) = _pallas_out_shapes(
+        lambda c, a: vote_combine_batch_fn(c, a, impl=PALLAS), copies, acc)
+    assert (shape == (B, T)) == (layout == "rows"), shape
 
 
 def test_chunked_stream_equals_monolithic():

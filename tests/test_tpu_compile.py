@@ -10,6 +10,7 @@ Each compile must contain the Pallas custom call and fit one chip's
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,10 +20,12 @@ from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 from repro.configs import get_smoke_config
 from repro.configs.base import ShapeConfig
-from repro.core.plan import AggConfig
+from repro.core.engine import build_batch_executable
+from repro.core.plan import AggConfig, compile_plan
 from repro.kernels.secure_agg.secure_agg import (mask_encrypt_batch,
                                                  unmask_decrypt_batch,
-                                                 vote_combine)
+                                                 vote_combine,
+                                                 vote_combine_rows)
 from repro.launch import steps as ST
 
 ROWS, ELEMS = 256, 1 << 16
@@ -91,11 +94,53 @@ def test_unmask_decrypt_batch_compiles_for_v5e(one_chip):
 
 @pytest.mark.parametrize("r", [3, 5])
 def test_vote_combine_compiles_for_v5e(one_chip, r):
-    """The engine flattens the (rows, T) batch into one vote call."""
+    """The flat vote, which a batch of fewer than 8 rows (the secure
+    train step's one row a rank) is flattened into."""
     flat = jax.ShapeDtypeStruct((ROWS * ELEMS,), jnp.uint32,
                                 sharding=one_chip)
     _compile(lambda *xs: vote_combine(list(xs[:r]), xs[r], interpret=False),
              *[flat] * (r + 1))
+
+
+@pytest.mark.parametrize("r", [3, 5])
+def test_vote_combine_rows_compiles_for_v5e(one_chip, r):
+    """The (rows, T) batch is voted in its own layout: the r + 2
+    double-buffered operand blocks fit the scoped VMEM."""
+    batch = jax.ShapeDtypeStruct((ROWS, ELEMS), jnp.uint32,
+                                 sharding=one_chip)
+    _compile(lambda *xs: vote_combine_rows(list(xs[:r]), xs[r],
+                                           interpret=False),
+             *[batch] * (r + 1))
+
+
+def _entry(hlo: str) -> list:
+    """The instructions of the ENTRY computation of an HLO module."""
+    lines = hlo.splitlines()
+    start = next(i for i, l in enumerate(lines) if l.startswith("ENTRY"))
+    end = next(i for i in range(start, len(lines)) if lines[i] == "}")
+    return [l.strip() for l in lines[start + 1:end]]
+
+
+def test_fl_round_executable_votes_without_relayouts(one_chip):
+    """The committee-256 batch executable (one session of n = 256 slots,
+    Pallas, donated, as the service runs it): each of the ring's 63
+    voted rounds is one vote call on the (256, T) rows as they are, and
+    no op of the program relayouts a batch into flat (k, 128) tiles."""
+    n, T = 256, 8192
+    plan = compile_plan(AggConfig(n_nodes=n, kernel_impl="pallas"))
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = build_batch_executable(plan, donate=True).lower(
+        sd((1, n, T), jnp.float32), sd((1,), jnp.uint32),
+        sd((1,), jnp.uint32), {}).compile()
+    ops = _entry(compiled.as_text())
+    out = f"u32[{n},{T}]"
+    votes = [op for op in ops if "vote_combine" in op
+             and 'custom_call_target="tpu_custom_call"' in op]
+    assert len(plan.rounds) == 63
+    assert len(votes) == 63
+    assert all(op.split(" = ", 1)[1].startswith(out + "{") for op in votes)
+    tiles = [op for op in ops if re.match(r"\S+ = u32\[\d+,128\]\{", op)]
+    assert not tiles, tiles[:3]
 
 
 @pytest.mark.parametrize("dp", [1, 4])
