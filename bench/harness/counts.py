@@ -91,3 +91,39 @@ def is_collective(op: str) -> bool:
     m = re.match(r"^%\S+ = \S+ (?P<code>[\w-]+)\(", op)
     code = m.group("code") if m else op
     return any(code.startswith(c) for c in COLLECTIVES)
+
+
+def ssd_flops_per_token(model: dict, seq_len: int) -> float:
+    """Forward FLOPs a token of one Mamba-2 layer's SSD takes by the
+    chunked algorithm (arXiv:2405.21060, Listing 1): its four
+    contractions over chunks of Q positions, the whole Q x Q block of
+    each: the scores C B^T (2 Q N), the diagonal blocks' outputs
+    (2 Q H P), the chunk states and the states' outputs (2 N H P each).
+    The chunk-to-chunk passing of the states (H P N a chunk) is left out."""
+    s = model["ssm"]
+    Q = min(s["chunk"], seq_len)
+    N, P = s["d_state"], s["head_dim"]
+    H = s["expand"] * model["d_model"] // P
+    return 2 * Q * N + 2 * Q * H * P + 4 * N * H * P
+
+
+def matmul_params(model: dict) -> int:
+    """Parameters a token meets in a matrix product: each layer's input
+    projections (z, x, B, C and dt) and output projection, and the output
+    head over the vocabulary (the tied embedding; the lookup is no
+    product)."""
+    d, s = model["d_model"], model["ssm"]
+    d_in = s["expand"] * d
+    heads = d_in // s["head_dim"]
+    layer = d * (2 * d_in + 2 * s["d_state"] + heads) + d_in * d
+    return model["n_layers"] * layer + model["vocab_size"] * d
+
+
+def train_step_flops(model: dict, batch: int, seq_len: int) -> float:
+    """Model FLOPs of one training step of a Mamba-2 language model,
+    whatever implements it: 6 x the matrix-product parameters x tokens,
+    plus every layer's SSD forward and backward (3 x its forward).  The
+    forward that rematerialisation runs again is not counted."""
+    tokens = batch * seq_len
+    return tokens * (6 * matmul_params(model) + 3 * model["n_layers"]
+                     * ssd_flops_per_token(model, seq_len))

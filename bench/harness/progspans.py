@@ -149,6 +149,7 @@ def host_spans(path: str) -> list:
 class Folded:
     stage_s: dict       # stage -> device seconds in the window, chip 0
     busy_s: float       # chip 0's busy union in the window
+    scoped_s: float     # chip 0's union of the ops under any stage
     idle_s: dict        # innermost host span -> chip 0's idle seconds
     spans: dict         # svc span -> (count, seconds) within the window
 
@@ -159,8 +160,12 @@ def fold(trace: tracefold.Trace, scopes: dict, svc: list) -> Folded:
     lo, hi = tracefold.window(trace)
     ops = tracefold.clip(trace.ops.get(0, []), lo, hi)
     stage: dict = collections.Counter()
+    scoped = []
     for name, a, b in ops:
-        stage[stage_of(scopes.get(name, ""))] += b - a
+        k = stage_of(scopes.get(name, ""))
+        stage[k] += b - a
+        if k != NO_STAGE:
+            scoped.append((a, b))
     busy = tracefold.union((a, b) for _, a, b in ops)
     idle = tracefold.attribute(tracefold.gaps(busy, lo, hi),
                                trace.spans + svc)
@@ -172,6 +177,7 @@ def fold(trace: tracefold.Trace, scopes: dict, svc: list) -> Folded:
     return Folded(
         stage_s={k: v * 1e-9 for k, v in stage.items()},
         busy_s=sum(b - a for a, b in busy) * 1e-9,
+        scoped_s=sum(b - a for a, b in tracefold.union(scoped)) * 1e-9,
         idle_s={k: v * 1e-9 for k, v in idle.items()},
         spans={k: (count[k], total[k] * 1e-9) for k in count})
 

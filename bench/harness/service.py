@@ -1,7 +1,10 @@
-"""The service cells: builds the secure-aggregation service a
-configuration describes, warms the shapes its traffic will use, and runs
-the measured window through the facade (``SecureAggregator.open_session``
-/ ``contribute`` / ``seal`` / ``pump``) as clients would.
+"""The ``service`` driver: the cells of a configuration without a
+``kind``.  It builds the secure-aggregation service the configuration
+describes, warms the shapes its traffic will use, and runs the measured
+window through the facade (``SecureAggregator.open_session`` /
+``contribute`` / ``seal`` / ``pump``) as clients would.  Its traffic is
+made by ``loadgen``, its end-to-end metrics are ``e2e.E2E`` and its
+reference and limits ``oracles``.
 
 Every call into a layer of the program is wrapped in a harness span
 (``bench.ingest``, ``bench.pump``, ``bench.wait``); with ``--trace 1``
@@ -10,17 +13,19 @@ what the host was doing while the device sat idle.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from typing import Callable, Optional
 
 import numpy as np
 
-from harness import loadgen, oracles
+from harness import e2e, loadgen, oracles
 from harness.loadgen import Load
 
 MAX_WAIT_S = 60.0       # how long past the window a late answer is awaited
+
+E2E = e2e.E2E
+LIMITS = oracles.LIMITS
 
 
 def build(config: dict, traffic: dict, seed: int):
@@ -54,13 +59,6 @@ class Window:
     ingest_s: list = dataclasses.field(default_factory=list)
     results: dict = dataclasses.field(default_factory=dict)  # i -> answer
     absorbed: dict = dataclasses.field(default_factory=dict)
-
-
-def spans(trace: bool) -> Callable:
-    if trace:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation
-    return lambda name: contextlib.nullcontext()
 
 
 def _open(agg, load: Load, i: int, now: float):
@@ -279,3 +277,59 @@ def compare(load: Load, w: Window, config: dict,
             wrong += oracles.wrong_bins(a, load.values[i], load.bins)
         out["wrong_bins"] = wrong
     return out
+
+
+def stage_totals(agg) -> dict:
+    """(count, seconds) of every ``stage.seconds`` span in the registry."""
+    hist = agg.metrics.snapshot()["histograms"]
+    return {k: (v["count"], v["total"]) for k, v in hist.items()
+            if k.startswith("stage.seconds")}
+
+
+class Cell:
+    """One run of a service cell, as ``run.run_cell`` drives it: set-up
+    (the data from the seed, the service, every batch shape warmed) when
+    it is made, then the window, the program's state freed, and the
+    comparison."""
+
+    def __init__(self, config: dict, traffic: dict, seed: int,
+                 seconds: float, chips: int):
+        self.config, self.traffic = config, traffic
+        self.load = loadgen.make_load(traffic, n_nodes=config["n_nodes"],
+                                      clip=config["clip"], seed=seed,
+                                      seconds=seconds)
+        self.agg = build(config, traffic, seed)
+        self.warmed = f"{warm(self.agg, self.load, traffic)} sessions warmed"
+        self._stages0 = stage_totals(self.agg)
+
+    def window(self, seconds: float, span, beat: Callable) -> Window:
+        run = run_closed if self.load.loop == "closed" else run_open
+        return run(self.agg, self.load, seconds, span, beat)
+
+    def stages(self) -> dict:
+        """The registry's ``stage.seconds`` spans over the window."""
+        s0 = self._stages0
+        return {k: (c - s0.get(k, (0, 0.0))[0], t - s0.get(k, (0, 0.0))[1])
+                for k, (c, t) in stage_totals(self.agg).items()}
+
+    def settle(self, w: Window) -> None:
+        w.absorbed = absorbed(self.agg)
+
+    def done(self, w: Window) -> str:
+        return f"{w.attempted} sessions, {w.revealed} revealed"
+
+    def notes(self, w: Window) -> list:
+        if not w.lateness_s:
+            return []
+        late = sorted(w.lateness_s)
+        return [f"generator ran late by {1000 * late[len(late) // 2]:.3f} "
+                f"ms (median), {1000 * late[-1]:.3f} ms (max)"]
+
+    def free(self) -> None:
+        del self.agg
+
+    def compare(self, w: Window, control: bool = False) -> dict:
+        return compare(self.load, w, self.config, control=control)
+
+    def failed(self, numbers: dict) -> int:
+        return numbers["unrevealed"] + numbers["absorbed"]

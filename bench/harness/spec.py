@@ -7,13 +7,22 @@ defines ``read(run)`` returning a number, or None where the run had
 nothing for it to read.  Adding a configuration, a traffic mix or a
 metric is adding its file and its entry; nothing here changes.
 
+A configuration's ``kind`` names the driver of its cells,
+``harness/<kind>.py`` (``service`` where the file has no ``kind``).  A
+driver defines ``Cell`` (set-up, window, comparison), ``E2E`` (the
+end-to-end metrics it can report, each ``f(window, setup_s)``) and
+``LIMITS`` (the limit of every number its comparison gives); a cell of a
+new kind is a new driver file plus its configuration, traffic and
+entries.
+
 A cell measured but kept out of ``BENCHMARK.json`` is held in
-``held/<cell>.json``, with the entries it would add (``workloads``,
-``end_to_end``, ``per_layer``); the benchmark's own runs never read it,
+``held/<cell>.json``, with the entries it would add (``configs``,
+``workloads``, ``end_to_end``, ``per_layer``); the benchmark's own runs never read it,
 the knee sweep and the harness's tests do.
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import re
@@ -33,8 +42,8 @@ def with_held(spec: dict) -> dict:
     out = {k: list(v) if isinstance(v, list) else v for k, v in spec.items()}
     for path in sorted((BENCH / "held").glob("*.json")):
         held = _json(path)
-        for kind in ("workloads", "end_to_end", "per_layer"):
-            out[kind] += held[kind]
+        for kind in ("configs", "workloads", "end_to_end", "per_layer"):
+            out[kind] += held.get(kind, [])
     return out
 
 
@@ -53,6 +62,19 @@ def _json(path: Path) -> dict:
 def config_of(spec: dict, w: dict, root: Path = ROOT) -> dict:
     entry = next(c for c in spec["configs"] if c["name"] == w["config"])
     return _json(root / entry["file"])
+
+
+def kind_of(config: dict) -> str:
+    return config.get("kind", "service")
+
+
+def driver(config: dict):
+    """The module ``harness/<kind>.py`` that runs the configuration's
+    cells."""
+    kind = kind_of(config)
+    if not re.fullmatch(r"[a-z][a-z0-9_]*", kind):
+        raise ValueError(f"bad configuration kind {kind!r}")
+    return importlib.import_module(f"harness.{kind}")
 
 
 def traffic_of(w: dict) -> dict:

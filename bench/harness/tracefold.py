@@ -15,10 +15,11 @@ dur_ns)``, ``Trace.spans`` a list of ``(name, start_ns, dur_ns)``.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import glob
 import os
-from typing import Optional
+from typing import Callable, Optional
 
 from harness import counts
 
@@ -31,6 +32,15 @@ NO_SPAN = "(outside any harness span)"
 class Trace:
     ops: dict            # chip index -> [(name, start_ns, dur_ns)]
     spans: list          # [(name, start_ns, dur_ns)] harness spans
+
+
+def spans(trace: bool) -> Callable:
+    """What a harness span is opened with: a profiler annotation in a
+    traced run, nothing otherwise."""
+    if trace:
+        from jax.profiler import TraceAnnotation
+        return TraceAnnotation
+    return lambda name: contextlib.nullcontext()
 
 
 def latest_xplane(log_dir: str) -> str:

@@ -22,7 +22,7 @@ BENCH = Path(__file__).resolve().parent
 sys.path.insert(0, str(BENCH))
 sys.path.insert(1, str(BENCH.parent / "src"))
 
-from harness import device, loadgen, service, spec, stats  # noqa: E402
+from harness import device, loadgen, service, spec, stats, tracefold  # noqa
 
 
 def main() -> int:
@@ -42,7 +42,7 @@ def main() -> int:
     first = loadgen.make_load(traffic, n_nodes=config["n_nodes"],
                               clip=config["clip"], seed=a.seed, seconds=1.0)
     service.warm(agg, first, traffic)
-    span = service.spans(False)
+    span = tracefold.spans(False)
     for r in [float(x) for x in a.rates.split(",")]:
         t = dict(traffic, rate_per_s=r)
         load = loadgen.make_load(t, n_nodes=config["n_nodes"],

@@ -7,12 +7,21 @@ asks for.  The cell ``<config>.<traffic>`` is looked up in
 ``BENCHMARK.json``; its configuration, traffic mix and per-layer metrics are
 files of their own under ``bench/`` (see ``harness/spec.py``).
 
-Set-up (``setup_s``) runs from process start to the first timed request:
-the data is made from the seed, the service is built, and every batch
-shape the traffic can flush is run once, from JAX's persistent compile
-cache after a cell's first run.  Then the window runs for ``--seconds``;
-nothing may compile inside it.  After the window, the program's state is
-freed and every answer due in it is compared with the plain reference.
+A configuration's ``kind`` picks the driver that runs its cells:
+``harness/<kind>.py``, ``service`` where the file names none.  The driver
+brings set-up, the measured window, the comparison with its plain
+reference, its end-to-end metrics (``E2E``) and the limits of the numbers
+it compares (``LIMITS``); this file brings what every cell shares: the
+chip and the compile cache, the clock, the profiler, the count of
+compiles, and the result line.
+
+Set-up (``setup_s``) runs from process start to the first timed request
+or step: the data and weights are made from the seed, the system under
+test is built, and every shape the traffic uses is run once, from JAX's
+persistent compile cache after a cell's first run.  Then the window runs
+for ``--seconds``; nothing may compile inside it.  After the window, the
+program's state is freed and what the window produced is compared with
+the plain reference.
 
 With ``--trace 0`` the result carries the cell's end-to-end metrics; with
 ``--trace 1`` the window is traced by the JAX profiler and the result
@@ -47,7 +56,7 @@ ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 sys.path.insert(1, str(ROOT / "src"))
 
-from harness import device, e2e, loadgen, oracles, service, spec  # noqa
+from harness import device, oracles, spec  # noqa: E402
 from harness import tracefold  # noqa: E402
 from harness.stalls import Stalls  # noqa: E402
 from harness.peaks import peaks_for  # noqa: E402
@@ -81,23 +90,16 @@ class GcPauses:
         gc.callbacks.remove(self)
 
 
-def stage_totals(agg) -> dict:
-    """(count, seconds) of every ``stage.seconds`` span in the registry."""
-    hist = agg.metrics.snapshot()["histograms"]
-    return {k: (v["count"], v["total"]) for k, v in hist.items()
-            if k.startswith("stage.seconds")}
-
-
 class Run:
     """What a per-layer metric's reader sees of one traced run."""
 
     def __init__(self, *, window, stages, trace, peaks, load, config,
                  traffic):
-        self.window = window            # service.Window
+        self.window = window            # the driver's record of the window
         self.stages = stages            # series -> (count, seconds), window
         self.trace = trace              # tracefold.Reduced
         self.peaks = peaks              # harness.peaks entry of the chip
-        self.load = load
+        self.load = load                # the driver's inputs of the run
         self.config = config
         self.traffic = traffic
 
@@ -123,6 +125,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     chips = w["chips"]
     config = config or spec.config_of(bench, w, root)
     traffic = traffic or spec.traffic_of(w)
+    driver = spec.driver(config)
     if check_device:
         cache = device.setup_compile_cache(jax, root)
         dev = device.require_chip(jax, chips)
@@ -134,16 +137,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
 
     length = float(traffic.get("trace_seconds", seconds) if trace
                    else seconds)
-    load = loadgen.make_load(traffic, n_nodes=config["n_nodes"],
-                             clip=config["clip"], seed=seed,
-                             seconds=length)
-    agg = service.build(config, traffic, seed)
-    warmed = service.warm(agg, load, traffic)
+    cell = driver.Cell(config, traffic, seed, length, chips)
     # set-up's garbage, and its writes to the compile cache, are settled
     # here, so that neither is paid for inside the window
     gc.collect()
     os.sync()
-    span = service.spans(trace)
+    span = tracefold.spans(trace)
     trace_dir = root / "bench" / "out" / "trace" / workload
     if trace:
         from jax.profiler import ProfileOptions
@@ -151,56 +150,52 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         opts = ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
-    before, stages0 = log.snapshot(), stage_totals(agg)
+    before = log.snapshot()
     compiled = (log.compiles, log.compile_s, log.hits)
     setup_s = time.monotonic() - T_START
-    run = service.run_closed if load.loop == "closed" else service.run_open
     with span(tracefold.WINDOW_SPAN), GcPauses() as pauses, \
             Stalls() as stalls:
-        win = run(agg, load, length, span, stalls.beat)
+        win = cell.window(length, span, stalls.beat)
     in_window = log.since(before)
-    stages = {k: (c - stages0.get(k, (0, 0.0))[0],
-                  t - stages0.get(k, (0, 0.0))[1])
-              for k, (c, t) in stage_totals(agg).items()}
+    stages = cell.stages()
     if trace:
         jax.profiler.stop_trace()
-    win.absorbed = service.absorbed(agg)
+    cell.settle(win)
     dev["memory_peak_bytes"] = device.peak_bytes(jax, chips)
-    say(f"set-up {setup_s:.3f} s ({warmed} sessions warmed; "
+    say(f"set-up {setup_s:.3f} s ({cell.warmed}; "
         f"{compiled[0]} programs compiled in {compiled[1]:.3f} s, "
         f"{compiled[2]} loaded from the cache); window "
-        f"{win.seconds:.3f} s, {win.attempted} sessions, {win.revealed} "
-        f"revealed; compiles in the window {in_window}")
-    if win.lateness_s:
-        late = sorted(win.lateness_s)
-        say(f"generator ran late by {1000 * late[len(late) // 2]:.3f} ms "
-            f"(median), {1000 * late[-1]:.3f} ms (max)")
+        f"{win.seconds:.3f} s, {cell.done(win)}; compiles in the window "
+        f"{in_window}")
+    for line in cell.notes(win):
+        say(line)
     say(f"garbage collections in the window: {pauses.count}, "
         f"{1000 * pauses.total:.3f} ms, longest {1000 * pauses.longest:.3f} "
         f"ms")
     for line in stalls.lines():
         say(line)
     say(f"peak device bytes {dev['memory_peak_bytes']}")
-    del agg
+    cell.free()
     gc.collect()                # the program's state goes before the check
 
-    numbers = service.compare(load, win, config)
+    numbers = cell.compare(win)
     numbers["window_compiles"] = sum(in_window.values())
     if control:
         # the program's own reading of this seed first, then the control
         for name, v in numbers.items():
             say(f"sound check {name} = {v}")
-        numbers.update(service.compare(load, win, config, control=True))
+        numbers.update(cell.compare(win, control=True))
         say("control: the reference one precision lower stands in for "
             "the program")
-    checks = {k: {"value": v, "limit": oracles.LIMITS[k]}
+    checks = {k: {"value": v, "limit": driver.LIMITS[k]}
               for k, v in numbers.items()}
-    failed = numbers["unrevealed"] + numbers["absorbed"]
+    failed = cell.failed(numbers)
     metrics = {}
     breakdown = None
     if not trace:
         for m in spec.metrics_of(bench, w, "end_to_end"):
-            metrics[m["name"]] = {"value": e2e.E2E[m["name"]](win, setup_s),
+            metrics[m["name"]] = {"value": driver.E2E[m["name"]](win,
+                                                                 setup_s),
                                   "unit": m["unit"]}
     else:
         red = tracefold.reduce(
@@ -208,7 +203,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             chips)
         ctx = Run(window=win, stages=stages, trace=red,
                   peaks=peaks_for(dev["kind"]) if check_device else None,
-                  load=load, config=config, traffic=traffic)
+                  load=cell.load, config=config, traffic=traffic)
         for m in spec.metrics_of(bench, w, "per_layer"):
             v = spec.reader(m["name"])(ctx)
             if v is not None:
